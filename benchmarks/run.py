@@ -41,6 +41,8 @@ def main() -> None:
         # must land before the figure modules (and benchmarks.common) import
         os.environ["REPRO_BENCH_SMOKE"] = "1"
     from benchmarks.common import print_rows
+    from repro.launch.platform import configure_compile_cache
+    configure_compile_cache()
 
     failures = []
     for name, modname in MODULES:

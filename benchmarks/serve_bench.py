@@ -633,11 +633,11 @@ def run_plans() -> list[dict]:
 def _run_distributed_leg(devices: int,
                          serve_mode: str = "exact-parity") -> dict:
     """Serve one dataset-handle workload on a ``devices``-wide mesh."""
-    import jax
     import numpy as np
     from jax.sharding import Mesh
 
-    mesh = Mesh(np.array(jax.devices()[:devices]), ("data",))
+    from repro.launch.platform import mesh_devices
+    mesh = Mesh(np.array(mesh_devices(devices)), ("data",))
     server = JoinServer(batch_slots=SLOTS, mesh=mesh, serve_mode=serve_mode)
     for tenant, rels in _workload(seed=7).items():
         server.register_dataset(tenant, rels)
@@ -711,19 +711,17 @@ def _check_psum_beats_gather(rows: list[dict]) -> None:
 
 
 def run_distributed() -> list[dict]:
-    """q/s + shuffle meters at 1/2/4/8 host devices, both serve modes.
+    """q/s + shuffle meters at 1/2/4/8 devices, both serve modes.
 
-    Spawns a child with ``--xla_force_host_platform_device_count=8`` when
-    this process has fewer devices (the flag must precede jax init); the
-    child emits one JSON row per (mesh size, serve mode) on stdout.
+    On the CPU (``JAX_PLATFORMS=cpu``) without enough host devices, spawns
+    a child with ``--xla_force_host_platform_device_count=8`` (the flag must
+    precede jax init); the child emits one JSON row per (mesh size, serve
+    mode) on stdout.  On an accelerator the legs run in this process, and a
+    host with fewer devices than the largest mesh is an error.
     """
-    import jax
-    if jax.device_count() < max(MESH_SIZES):
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " "
-                            "--xla_force_host_platform_device_count="
-                            f"{max(MESH_SIZES)}").strip()
-        env.setdefault("JAX_PLATFORMS", "cpu")
+    from repro.launch.platform import cpu_device_env
+    env = cpu_device_env(max(MESH_SIZES))
+    if env is not None:
         out = subprocess.run(
             [sys.executable, "-m", "benchmarks.serve_bench",
              "--distributed-child"],

@@ -68,8 +68,9 @@ def block_index(keys: jnp.ndarray, num_blocks: int, seed) -> jnp.ndarray:
     return (hash2(keys, seed) & u32(num_blocks - 1)).astype(jnp.int32)
 
 
-def lane_masks(keys: jnp.ndarray, seed) -> jnp.ndarray:
-    """[..., 8] uint32 — the one-bit-per-lane masks for each key.
+def lane_mask_words(keys: jnp.ndarray, seed) -> list[jnp.ndarray]:
+    """The 8 one-bit-per-lane masks of each key, one keys-shaped uint32
+    array per filter word (the form the Pallas kernels consume).
 
     Scalar numpy literals per lane (not a stacked device array) so this
     traces cleanly inside Pallas kernels (see core.hashing note).
@@ -80,7 +81,12 @@ def lane_masks(keys: jnp.ndarray, seed) -> jnp.ndarray:
         # bit position in lane = top 5 bits of (h * salt)
         bits = (h * u32(s)) >> u32(27)
         lanes.append((u32(1) << bits).astype(jnp.uint32))
-    return jnp.stack(lanes, axis=-1)
+    return lanes
+
+
+def lane_masks(keys: jnp.ndarray, seed) -> jnp.ndarray:
+    """[..., 8] uint32 — the one-bit-per-lane masks for each key."""
+    return jnp.stack(lane_mask_words(keys, seed), axis=-1)
 
 
 def empty(num_blocks: int, seed: int = 0) -> BloomFilter:

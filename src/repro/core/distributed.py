@@ -50,7 +50,6 @@ from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import bloom
@@ -111,19 +110,11 @@ def planned_bucket_cap(local_rows: int, k: int, overlap: float, *,
     return max(int(mean + guard), floor)
 
 
-def axis_size(a: str):
-    """Size of a mapped mesh axis.  ``jax.lax.axis_size`` only exists in
-    newer JAX; ``psum(1, axis)`` is the classic constant-folding idiom."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(a)
-    return jax.lax.psum(1, a)
-
-
 def combined_axis_index(axes: Sequence[str]) -> jnp.ndarray:
     """Linear device index over possibly-multiple mesh axes (major first)."""
     idx = jnp.zeros((), jnp.int32)
     for a in axes:
-        idx = idx * axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
@@ -186,7 +177,7 @@ def shuffle_by_key(rel: Relation, k: int, cap: int, axes: Sequence[str],
     # each factor along ITS mesh axis — the composition is the all_to_all
     # over the combined (major-first) device index.  Exchanging always on
     # the leading dim would route the later axes by SOURCE index (bug).
-    sizes = [axis_size(a) for a in axes]
+    sizes = [jax.lax.axis_size(a) for a in axes]
     recv = []
     for x in (keys, vals, valid):
         x = x.reshape(*sizes, cap)
@@ -307,7 +298,7 @@ def dist_prepare_stage(rels: Sequence[Relation], num_blocks: int,
     axes = tuple(axes)
     k = 1
     for a in axes:
-        k *= axis_size(a)
+        k *= jax.lax.axis_size(a)
     n_rels = len(rels)
     local_n = rels[0].capacity
     total_counts = jax.lax.psum(jnp.stack([r.count() for r in rels]), axes)
@@ -604,11 +595,11 @@ def make_distributed_join(mesh: Mesh,
                               **meters)
 
     rel_spec = [P(axes), P(axes), P(axes)] * n_rels
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(), *rel_spec),
-                   out_specs=DistJoinResult(
-                       *([P()] * len(DistJoinResult._fields))),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(), *rel_spec),
+                       out_specs=DistJoinResult(
+                           *([P()] * len(DistJoinResult._fields))),
+                       check_vma=False)
 
     @jax.jit
     def run(rels: Sequence[Relation], d_dt=0.0):
@@ -689,9 +680,9 @@ def make_serve_prepare(mesh: Mesh, axes: Sequence[str], *, n_rels: int,
         population=P(None, axes) if merge == "psum" else P(),
         shuffled_tuple_bytes=P(), device_shuffled_bytes=P(),
         bucket_overflow=P(), device_dropped=P(), filter_bytes=P())
-    fn = shard_map(batched, mesh=mesh,
-                   in_specs=(flat_spec, P(), P()),
-                   out_specs=out_spec, check_rep=False)
+    fn = jax.shard_map(batched, mesh=mesh,
+                       in_specs=(flat_spec, P(), P()),
+                       out_specs=out_spec, check_vma=False)
 
     @jax.jit
     def run(rels_b: Sequence[Relation], words_b, seeds):
@@ -720,11 +711,11 @@ def make_serve_sample(mesh: Mesh, axes: Sequence[str], *, n_rels: int,
 
     flat_spec = tuple(P(None, axes) for _ in range(3 * n_rels))
     stats_spec = StratumStats(P(), P(), P(), P(), P())
-    fn = shard_map(batched, mesh=mesh,
-                   in_specs=(flat_spec, _local_strata_spec(axes), P(), P(),
-                             P(), P()),
-                   out_specs=(P(), P(), P(), P(), stats_spec),
-                   check_rep=False)
+    fn = jax.shard_map(batched, mesh=mesh,
+                       in_specs=(flat_spec, _local_strata_spec(axes), P(), P(),
+                                 P(), P()),
+                       out_specs=(P(), P(), P(), P(), stats_spec),
+                       check_vma=False)
 
     @jax.jit
     def run(sorted_rels, lstrata, mkeys, mvalid, b_merged, seeds):
@@ -750,10 +741,10 @@ def make_serve_exact(mesh: Mesh, axes: Sequence[str], *, n_rels: int,
         return jax.vmap(per_query)(*args)
 
     flat_spec = tuple(P(None, axes) for _ in range(3 * n_rels))
-    fn = shard_map(batched, mesh=mesh,
-                   in_specs=(flat_spec, _local_strata_spec(axes),
-                             Strata(P(), P(), P(), P(), P())),
-                   out_specs=(P(), P()), check_rep=False)
+    fn = jax.shard_map(batched, mesh=mesh,
+                       in_specs=(flat_spec, _local_strata_spec(axes),
+                                 Strata(P(), P(), P(), P(), P())),
+                       out_specs=(P(), P()), check_vma=False)
 
     @jax.jit
     def run(sorted_rels, lstrata, mstrata):
@@ -791,11 +782,11 @@ def make_serve_sample_psum(mesh: Mesh, axes: Sequence[str], *, n_rels: int,
     flat_spec = tuple(P(None, axes) for _ in range(3 * n_rels))
     sharded = P(None, axes)
     stats_spec = StratumStats(sharded, sharded, sharded, sharded, sharded)
-    fn = shard_map(batched, mesh=mesh,
-                   in_specs=(flat_spec, _local_strata_spec(axes), sharded,
-                             P()),
-                   out_specs=(P(), P(), P(), P(), stats_spec),
-                   check_rep=False)
+    fn = jax.shard_map(batched, mesh=mesh,
+                       in_specs=(flat_spec, _local_strata_spec(axes), sharded,
+                                 P()),
+                       out_specs=(P(), P(), P(), P(), stats_spec),
+                       check_vma=False)
 
     @jax.jit
     def run(sorted_rels, lstrata, b, seeds):
@@ -821,9 +812,9 @@ def make_serve_exact_psum(mesh: Mesh, axes: Sequence[str], *, n_rels: int,
         return jax.vmap(per_query)(*args)
 
     flat_spec = tuple(P(None, axes) for _ in range(3 * n_rels))
-    fn = shard_map(batched, mesh=mesh,
-                   in_specs=(flat_spec, _local_strata_spec(axes)),
-                   out_specs=(P(), P()), check_rep=False)
+    fn = jax.shard_map(batched, mesh=mesh,
+                       in_specs=(flat_spec, _local_strata_spec(axes)),
+                       out_specs=(P(), P()), check_vma=False)
 
     @jax.jit
     def run(sorted_rels, lstrata):
@@ -848,6 +839,6 @@ def make_serve_filter_build(mesh: Mesh, axes: Sequence[str], *,
         return or_reduce(bloom.build(keys, valid, num_blocks, seed).words,
                          axes)
 
-    fn = shard_map(build, mesh=mesh, in_specs=(P(axes), P(axes), P()),
-                   out_specs=P(), check_rep=False)
+    fn = jax.shard_map(build, mesh=mesh, in_specs=(P(axes), P(axes), P()),
+                       out_specs=P(), check_vma=False)
     return jax.jit(fn)

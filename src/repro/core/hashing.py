@@ -91,10 +91,11 @@ def counter_hash(seed, stratum, counter, lane) -> jnp.ndarray:
 
 
 def bounded(h: jnp.ndarray, bound: jnp.ndarray) -> jnp.ndarray:
-    """Map a uint32 hash into [0, bound) (bound >= 1, int32).
+    """Map a uint32 hash into [0, bound) (bound >= 0, int32; 0 acts as 1).
 
     Plain modulo; the bias is O(bound / 2^32), negligible for the stratum
-    sizes we draw from (documented in DESIGN.md).
+    sizes we draw from (documented in DESIGN.md).  The clamp runs in the
+    signed dtype before the cast: Mosaic has no unsigned max.
     """
-    b = jnp.maximum(u32(bound), _U(1))
+    b = u32(jnp.maximum(bound, 1))
     return (h % b).astype(jnp.int32)

@@ -176,14 +176,14 @@ def prepare_stage_pre(rels: Sequence[Relation], filter_words: jnp.ndarray,
 def prepare_stage_kernels(rels: Sequence[Relation], num_blocks: int,
                           max_strata: int, seed, *,
                           filter_words: Optional[jnp.ndarray] = None,
-                          interpret: bool = True) -> PrepareOut:
+                          interpret: bool | None = None) -> PrepareOut:
     """Kernel-backed :func:`prepare_stage` / :func:`prepare_stage_pre`.
 
     Same stage contract, Pallas execution: per-input filters come from the
     hash kernel + scatter-OR commit (or arrive PREBUILT as ``filter_words``
     ``[n_inputs, num_blocks, W]`` — e.g. the serving engine's per-dataset
     cache), the AND-merge happens on the packed words, and the probe runs
-    through the VMEM-resident filter kernel.  ``seed`` is the FILTER seed
+    through the probe kernel.  ``seed`` is the FILTER seed
     and may be a traced array (the engine's decoupled ``filter_seed``);
     results are bit-identical to the jnp stages — the kernels share the
     uint32 hash math (asserted in ``tests/test_kernels.py``).
@@ -211,7 +211,7 @@ def prepare_stage_kernels(rels: Sequence[Relation], num_blocks: int,
 def prepare_stage_kernels_batched(rels: Sequence[Relation],
                                   filter_words: jnp.ndarray,
                                   max_strata: int, seeds, *,
-                                  interpret: bool = True) -> PrepareOut:
+                                  interpret: bool | None = None) -> PrepareOut:
     """Slot-batched kernel prepare: the engine's fused-batch counterpart.
 
     ``rels`` carry slot-stacked ``[B, N]`` arrays, ``filter_words`` is
@@ -317,7 +317,7 @@ def sample_stage_kernels(sorted_rels: Sequence[Relation], strata: Strata,
                          b_i: jnp.ndarray, b_max: int, seed, *,
                          agg: str = "sum", confidence: float = 0.95,
                          expr: str = "sum",
-                         interpret: bool = True):
+                         interpret: bool | None = None):
     """Kernel-backed :func:`sample_stage` (two-way, non-dedup): the fused
     draw->gather->f->reduce Pallas sampler + the shared estimate stage."""
     from repro.kernels import ops as kops
@@ -333,7 +333,8 @@ def sample_stage_kernels_batched(sorted_rels: Sequence[Relation],
                                  strata: Strata, b_i: jnp.ndarray,
                                  b_max: int, seeds, *,
                                  agg: str = "sum", confidence: float = 0.95,
-                                 expr: str = "sum", interpret: bool = True):
+                                 expr: str = "sum",
+                                 interpret: bool | None = None):
     """Slot-batched kernel sample stage (engine counterpart).
 
     Inputs are slot-stacked (``[B, ...]`` leaves, as emitted by the batched
@@ -414,7 +415,7 @@ def approx_join(rels: Sequence[Relation],
     duplicate edges and switches to the Horvitz-Thompson estimator.
     ``use_kernels=True`` routes filter build/probe and the (two-way,
     non-dedup) sampler through the Pallas kernels (kernels/ops.py) —
-    bit-identical results, fused VMEM execution on TPU.
+    bit-identical results; Mosaic-compiled kernels on a TPU.
     """
     f_fn, exact_fn = EXPRS[expr] if f is None else (f, None)
     n = len(rels)
@@ -428,10 +429,7 @@ def approx_join(rels: Sequence[Relation],
     t0 = time.perf_counter()
     num_blocks = bloom.num_blocks_for(max_n, fp_rate)
     if use_kernels:
-        from repro.kernels import ops as kops
-        interp = kops.use_interpret()
-        prep = prepare_stage_kernels(rels, num_blocks, S, seed,
-                                     interpret=interp)
+        prep = prepare_stage_kernels(rels, num_blocks, S, seed)
     else:
         prep = prepare_stage(rels, num_blocks, S, seed)
     sorted_rels, strata = prep.sorted_rels, prep.strata
@@ -488,7 +486,7 @@ def approx_join(rels: Sequence[Relation],
     if use_kernels and not dedup and n == 2 and f is None:
         value, err, cnt, dof, kstats = sample_stage_kernels(
             sorted_rels, strata, b_i, b_max, seed + 1, agg=agg,
-            confidence=budget.confidence, expr=expr, interpret=interp)
+            confidence=budget.confidence, expr=expr)
         sample = _kernel_sample_result(kstats)
     else:
         sample = sample_edges(sorted_rels, strata, b_i, b_max, seed + 1, f_fn)

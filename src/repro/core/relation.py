@@ -68,6 +68,30 @@ def pad_to(rel: Relation, capacity: int) -> Relation:
     )
 
 
+def pad_shards(rel: Relation, num_shards: int, capacity: int) -> Relation:
+    """Pad a relation to ``capacity`` rows with the padding spread evenly
+    over ``num_shards`` equal row blocks.
+
+    Block ``d`` holds the next ``N // num_shards`` rows of ``rel`` (one more
+    for the first ``N % num_shards`` blocks), then invalid rows.  A mesh
+    shards rows in contiguous blocks, so every device gets an equal share of
+    the real rows, in order; :func:`pad_to` would put all the padding, and
+    none of the rows, on the last devices.
+    """
+    n = rel.capacity
+    if num_shards == 1 or n == capacity:
+        return pad_to(rel, capacity)
+    per, rem = divmod(capacity, num_shards)
+    assert rem == 0 and n <= capacity, (n, num_shards, capacity)
+    base, extra = divmod(n, num_shards)
+    sizes = base + (np.arange(num_shards) < extra)
+    starts = np.cumsum(sizes) - sizes
+    shard = np.repeat(np.arange(num_shards), sizes)
+    dest = shard * per + np.arange(n) - starts[shard]
+    return Relation(*(jnp.zeros(capacity, x.dtype).at[dest].set(x)
+                      for x in rel))
+
+
 def bucket_capacity(n: int, minimum: int = 1) -> int:
     """Round a row count up to the next power of two (shape-class bucketing).
 
@@ -103,6 +127,17 @@ def shard_to_mesh(rel: Relation, mesh, axes: Sequence[str]) -> Relation:
     from jax.sharding import NamedSharding, PartitionSpec
     sh = NamedSharding(mesh, PartitionSpec(tuple(axes)))
     return Relation(*(jax.device_put(x, sh) for x in rel))
+
+
+def place_rows(rel: Relation, capacity: int, mesh=None,
+               axes: Sequence[str] = ()) -> Relation:
+    """Admit a relation: pad it to ``capacity`` rows and, on a mesh, shard
+    its rows over ``axes`` with every device's row block padded
+    (:func:`pad_shards`), so each device holds its share of the real rows."""
+    if mesh is None:
+        return pad_to(rel, capacity)
+    k = int(np.prod([mesh.shape[a] for a in axes]))
+    return shard_to_mesh(pad_shards(rel, k, capacity), mesh, axes)
 
 
 def sort_by_key(rel: Relation) -> Relation:

@@ -30,7 +30,7 @@ from collections import deque
 from typing import NamedTuple, Sequence
 
 from repro.core.relation import (Relation, bucket_capacity, concatenate,
-                                 pad_to)
+                                 pad_shards)
 
 
 class WindowSpec(NamedTuple):
@@ -108,15 +108,18 @@ class WindowBuffer:
         return due, expired
 
 
-def window_relations(subs: Sequence[SubWindow],
-                     minimum: int = 1) -> list[Relation]:
+def window_relations(subs: Sequence[SubWindow], minimum: int = 1,
+                     num_shards: int = 1) -> list[Relation]:
     """Assemble a window's per-side relations from its sub-windows.
 
     Concatenation order is arrival order; the result is padded to the
     window's pow2 capacity bucket (invalid padding rows), so every window of
-    a given spec lands in ONE serving shape class.
+    a given spec lands in ONE serving shape class.  For a mesh of
+    ``num_shards`` devices each device's row block is padded
+    (:func:`~repro.core.relation.pad_shards`), not the tail.
     """
     n_sides = len(subs[0].rels)
     cap = bucket_capacity(len(subs) * subs[0].rels[0].capacity, minimum)
-    return [pad_to(concatenate([s.rels[side] for s in subs]), cap)
+    return [pad_shards(concatenate([s.rels[side] for s in subs]),
+                       num_shards, cap)
             for side in range(n_sides)]

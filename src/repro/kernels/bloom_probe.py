@@ -2,83 +2,77 @@
 
 Every tuple of every input probes the join filter once (§3.1), so this is
 the paper's dominant per-tuple cost.  Batched layout (one slot per query of
-an engine batch, 2-D grid over ``(batch_slot, key_block)``):
+an engine batch, 2-D grid over ``(batch_slot, key_block)``), two passes:
 
-  * the packed filters are STACKED ``[B, num_blocks, 8]`` uint32 with
-    per-slot VMEM residency: the BlockSpec index map pins slot ``b``'s
-    ``[num_blocks, 8]`` filter to ``(b, 0, 0)``, so it stays resident across
-    that slot's whole key sweep and is swapped exactly once per slot — it is
-    small by construction (Eq. 27: ~1.2 bytes/key at 1% FPR) and every key
-    touches one random 256-bit block of it, which is exactly what VMEM is
-    for;
-  * keys stream through in ``[1, BLOCK]`` slices (double-buffered by
-    Pallas);
-  * per-slot seeds are runtime array operands (one-element VMEM blocks), so
-    one compiled executable serves every seed of a mixed-seed batch;
-  * per key: one VMEM gather of its 8-word block + lane-mask compare — no
-    HBM round-trips per probe, unlike the GPU pointer-chase formulation.
+  1. XLA hashes every key to its filter block (``bloom.block_index``),
+     gathers that 8-word block from the slot's packed ``[num_blocks, 8]``
+     filter and lays the words out lane-major, ``[B, 8, N/128, 128]``.
+     Mosaic lowers no gather over a VMEM-resident table of this size, so
+     the gather, and the index it needs, stay in XLA;
+  2. this kernel streams ``[block/128, 128]`` key tiles with their 8 word
+     planes, computes the lane masks and reduces the 8-lane compare to
+     one membership bit per key.
 
-VMEM budget: the whole stacked filter must fit, ``B * filter_bytes`` <= ~8
-MiB (e.g. 8 slots of num_blocks <= 2^15 = 1 Mi keys each at 1% FPR per
-shard) + small key/seed/output blocks.  The wrapper asserts this — the
-budget is deliberately charged for ALL slots even though only one is
-resident per grid step, covering Pallas' cross-slot double buffering.
+Per-slot seeds are runtime array operands (the ``[B]`` vector in SMEM), so
+one compiled executable serves every seed of a mixed-seed batch.  VMEM per
+step is a few fixed-size tiles, independent of the filter size and of B.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import bloom
+from repro.kernels import use_interpret
+from repro.kernels.bloom_build import DEFAULT_BLOCK, LANES, key_tiles
 
-DEFAULT_BLOCK = 2048
-VMEM_FILTER_LIMIT = 8 * 1024 * 1024  # bytes of VMEM we allow the filters
 
-
-def _kernel(seed_ref, words_ref, keys_ref, out_ref, *, num_blocks: int):
-    seed = seed_ref[0]                  # this slot's seed (runtime operand)
-    keys = keys_ref[...]                # [1, BLOCK]
-    blk = bloom.block_index(keys, num_blocks, seed)
-    masks = bloom.lane_masks(keys, seed)
-    words = words_ref[...][0]           # [num_blocks, 8], VMEM-resident
-    gathered = words[blk[0]]            # [BLOCK, 8] vector gather in VMEM
-    out_ref[...] = jnp.all((gathered & masks[0]) == masks[0], axis=-1)[None]
+def _kernel(seed_ref, keys_ref, words_ref, out_ref):
+    seed = seed_ref[pl.program_id(0)]   # this slot's seed (SMEM scalar)
+    hit = None
+    for w, m in enumerate(bloom.lane_mask_words(keys_ref[...], seed)):
+        ok = (words_ref[w] & m) == m
+        hit = ok if hit is None else hit & ok
+    out_ref[...] = hit
 
 
 def bloom_probe_batched(words: jnp.ndarray, keys: jnp.ndarray,
                         seeds: jnp.ndarray, block: int = DEFAULT_BLOCK,
-                        interpret: bool = True) -> jnp.ndarray:
+                        interpret: bool | None = None) -> jnp.ndarray:
     """Membership mask bool [B, N]: each slot's keys against its own filter.
 
     ``words`` is the stacked ``[B, num_blocks, 8]`` filter layout; ``seeds``
     is uint32 ``[B]`` (runtime operands — zero recompiles across seeds).
     """
     B, n = keys.shape
-    nb = words.shape[1]
-    assert words.shape[0] == B and seeds.shape == (B,), \
+    nb, W = words.shape[1], bloom.WORDS_PER_BLOCK
+    assert words.shape == (B, nb, W) and seeds.shape == (B,), \
         (words.shape, keys.shape, seeds.shape)
-    assert n % block == 0, f"pad keys to a multiple of {block} (got {n})"
-    assert B * nb * 8 * 4 <= VMEM_FILTER_LIMIT, \
-        f"stacked filters too large for VMEM residency: {B * nb * 32} bytes"
-    return pl.pallas_call(
-        functools.partial(_kernel, num_blocks=nb),
+    tiles = key_tiles(keys, block)
+    blk = bloom.block_index(keys, nb, seeds[:, None])
+    rows = jnp.take_along_axis(words, blk[..., None], axis=1)  # [B, N, 8]
+    planes = rows.transpose(0, 2, 1).reshape(B, W, n // LANES, LANES)
+    rb = block // LANES
+    tile = pl.BlockSpec((None, rb, LANES), lambda b, i: (b, i, 0))
+    hits = pl.pallas_call(
+        _kernel,
         grid=(B, n // block),
-        in_specs=[pl.BlockSpec((1,), lambda b, i: (b,)),
-                  pl.BlockSpec((1, nb, 8), lambda b, i: (b, 0, 0)),  # pinned
-                  pl.BlockSpec((1, block), lambda b, i: (b, i))],
-        out_specs=pl.BlockSpec((1, block), lambda b, i: (b, i)),
-        out_shape=jax.ShapeDtypeStruct((B, n), jnp.bool_),
-        interpret=interpret,
-    )(seeds, words, keys)
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), tile,
+                  pl.BlockSpec((None, W, rb, LANES),
+                               lambda b, i: (b, 0, i, 0))],
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct(tiles.shape, jnp.bool_),
+        interpret=use_interpret(interpret),
+    )(seeds, tiles, planes)
+    return hits.reshape(B, n)
 
 
 def bloom_probe(words: jnp.ndarray, keys: jnp.ndarray, seed=0,
                 block: int = DEFAULT_BLOCK,
-                interpret: bool = True) -> jnp.ndarray:
+                interpret: bool | None = None) -> jnp.ndarray:
     """Membership mask bool [N] for keys against the packed filter words.
 
     Single-slot convenience over :func:`bloom_probe_batched` (B = 1).

@@ -5,9 +5,9 @@ ALL padding lives here: the raw kernels in ``bloom_build``/``bloom_probe``/
 operands up to those multiples and truncates the results back — so padded
 tail keys/strata can never flip a result (property-tested for pow2 and
 non-pow2 lengths in ``tests/test_kernels.py``).  The wrappers also handle
-the scatter-OR commit for the build kernel, StratumStats assembly for the
-sampler, and the interpret-mode switch (this container is CPU-only; on a TPU
-backend the kernels compile to Mosaic).
+the scatter-OR commit for the build kernel and StratumStats assembly for the
+sampler.  ``interpret=None`` follows the backend (``kernels.use_interpret``):
+Mosaic-compiled on a TPU, Pallas interpret mode anywhere else.
 
 Seeds are RUNTIME ARRAY OPERANDS throughout — never static jit arguments —
 so one compiled executable per shape class serves every seed (N distinct
@@ -24,7 +24,6 @@ tested bit-exact.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Sequence
 
 import jax
@@ -36,16 +35,9 @@ from repro.core.estimators import StratumStats
 from repro.core.relation import Relation
 from repro.core.sampling import Strata
 from repro.kernels import bloom_build as _build
+from repro.kernels import use_interpret  # noqa: F401 — re-exported
 from repro.kernels import bloom_probe as _probe
 from repro.kernels import edge_sample as _edge
-
-
-def use_interpret() -> bool:
-    """Pallas interpret mode unless we are actually on TPU (env-overridable)."""
-    forced = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if forced is not None:
-        return forced not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
 
 
 def _pad1(x: jnp.ndarray, mult: int, fill=0):
@@ -83,7 +75,7 @@ def _seedvec(seed) -> jnp.ndarray:
 @functools.partial(jax.jit, static_argnames=("num_blocks", "interpret"))
 def build_filter_batched(keys: jnp.ndarray, valid: jnp.ndarray,
                          num_blocks: int, seeds: jnp.ndarray,
-                         interpret: bool = True) -> jnp.ndarray:
+                         interpret: bool | None = None) -> jnp.ndarray:
     """Kernel-backed per-slot bloom build: packed words uint32 [B, nb, 8].
 
     ``keys``/``valid`` are slot-stacked ``[B, N]``; ``seeds`` uint32 ``[B]``
@@ -99,7 +91,7 @@ def build_filter_batched(keys: jnp.ndarray, valid: jnp.ndarray,
 
 
 def build_filter(keys: jnp.ndarray, valid: jnp.ndarray, num_blocks: int,
-                 seed=0, interpret: bool = True) -> bloom.BloomFilter:
+                 seed=0, interpret: bool | None = None) -> bloom.BloomFilter:
     """Kernel-backed bloom.build: hash kernel + XLA scatter-OR commit.
 
     Unjitted shim over the jitted batched kernel (B = 1): the seed
@@ -118,7 +110,7 @@ def build_filter(keys: jnp.ndarray, valid: jnp.ndarray, num_blocks: int,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def probe_filter_batched(words: jnp.ndarray, keys: jnp.ndarray,
                          seeds: jnp.ndarray,
-                         interpret: bool = True) -> jnp.ndarray:
+                         interpret: bool | None = None) -> jnp.ndarray:
     """Kernel-backed per-slot membership probe: bool [B, N].
 
     ``words`` is the stacked ``[B, nb, 8]`` filter layout (each slot probes
@@ -132,7 +124,7 @@ def probe_filter_batched(words: jnp.ndarray, keys: jnp.ndarray,
 
 
 def probe_filter(words: jnp.ndarray, keys: jnp.ndarray, seed=0,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: bool | None = None) -> jnp.ndarray:
     """Kernel-backed bloom.contains (unjitted B = 1 shim, see build_filter)."""
     return probe_filter_batched(words[None], keys[None], _seedvec(seed),
                                 interpret=interpret)[0]
@@ -150,7 +142,7 @@ def sample_stats_batched(values1: jnp.ndarray, values2: jnp.ndarray,
                          joinable: jnp.ndarray, population: jnp.ndarray,
                          b_i: jnp.ndarray, seeds: jnp.ndarray, b_max: int,
                          expr: str = "sum",
-                         interpret: bool = True) -> StratumStats:
+                         interpret: bool | None = None) -> StratumStats:
     """Kernel-backed per-slot Algorithm-2 pass: StratumStats with [B, S]
     leaves.  ``starts``/``counts`` are ``[B, 2, S]``; ``seeds`` uint32 [B]."""
     S = strata_keys.shape[1]
@@ -172,7 +164,7 @@ def sample_stats_2way(values1: jnp.ndarray, values2: jnp.ndarray,
                       joinable: jnp.ndarray, population: jnp.ndarray,
                       b_i: jnp.ndarray, b_max: int, seed=0,
                       expr: str = "sum",
-                      interpret: bool = True) -> StratumStats:
+                      interpret: bool | None = None) -> StratumStats:
     """Kernel-backed two-way Algorithm-2 pass returning StratumStats
     (unjitted B = 1 shim, see build_filter)."""
     stats = sample_stats_batched(
@@ -187,8 +179,6 @@ def sample_stats(sorted_rels: Sequence[Relation], strata: Strata,
                  expr: str = "sum", interpret: bool | None = None) -> StratumStats:
     """Convenience: Strata-level entry point (two-way only)."""
     assert len(sorted_rels) == 2, "kernel path is two-way; use core.sampling"
-    if interpret is None:
-        interpret = use_interpret()
     return sample_stats_2way(
         sorted_rels[0].values, sorted_rels[1].values,
         strata.keys, strata.starts, strata.counts,

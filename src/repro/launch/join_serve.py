@@ -10,21 +10,22 @@ Usage:
       --queries-per-tenant 8 --slots 4
 
   # distributed: one batched step spans all mesh devices
-  PYTHONPATH=src python -m repro.launch.join_serve --mesh 8
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.join_serve --mesh 8
 
   # always-on async tier: event-loop replicas, continuous batching,
   # tenant sharding + work stealing behind one front door
   PYTHONPATH=src python -m repro.launch.join_serve --async --replicas 2
 
-``--mesh N`` re-execs under ``--xla_force_host_platform_device_count`` when
-the process has fewer than N devices (the flag must be set before jax
-initializes), then serves through the shard_map pipeline.
+``--mesh N`` serves through the shard_map pipeline over the first N
+devices.  On the CPU (``JAX_PLATFORMS=cpu``) it re-execs under
+``--xla_force_host_platform_device_count`` when needed (the flag must be set
+before jax initializes); on an accelerator a mesh larger than the host is an
+error.
 """
 
 from __future__ import annotations
 
 import argparse
-import os
 import subprocess
 import sys
 import time
@@ -32,7 +33,10 @@ import time
 from repro.core.budget import QueryBudget
 from repro.core.cost import CostModel
 from repro.data.synthetic import overlapping_relations
+from repro.launch.platform import (configure_compile_cache, cpu_device_env,
+                                   mesh_devices as _mesh_devices)
 from repro.runtime.async_serve import AsyncJoinFrontDoor
+from repro.runtime.fault import InjectedFault
 from repro.runtime.join_serve import JoinRequest, JoinServer
 from repro.runtime.telemetry import (Tracer, dump_chrome_trace,
                                      format_reconciliation,
@@ -45,10 +49,9 @@ def run(*, tenants: int = 4, queries_per_tenant: int = 8, slots: int = 4,
         trace_out: str | None = None) -> dict:
     mesh = None
     if mesh_devices:
-        import jax
         import numpy as np
         from jax.sharding import Mesh
-        mesh = Mesh(np.array(jax.devices()[:mesh_devices]), ("data",))
+        mesh = Mesh(np.array(_mesh_devices(mesh_devices)), ("data",))
     tracer = Tracer(enabled=True) if trace_out else None
     server = JoinServer(batch_slots=slots,
                         cost_model=CostModel(beta_compute=1e-7, epsilon=1e-3),
@@ -125,10 +128,9 @@ def run_async(*, tenants: int = 4, queries_per_tenant: int = 8,
     def factory(i: int) -> JoinServer:
         mesh = None
         if mesh_devices:
-            import jax
             import numpy as np
             from jax.sharding import Mesh
-            mesh = Mesh(np.array(jax.devices()[:mesh_devices]), ("data",))
+            mesh = Mesh(np.array(_mesh_devices(mesh_devices)), ("data",))
         return JoinServer(batch_slots=slots,
                           cost_model=CostModel(beta_compute=1e-7,
                                                epsilon=1e-3),
@@ -160,7 +162,9 @@ def run_async(*, tenants: int = 4, queries_per_tenant: int = 8,
         for f in futs:
             try:
                 reqs.append(f.result(timeout=600))
-            except BaseException:  # noqa: BLE001 — the injected fault
+            except InjectedFault:
+                if not kill_after:
+                    raise
                 killed += 1
         if kill_after:
             fd.maybe_failover()
@@ -236,21 +240,12 @@ def main() -> None:
     args = ap.parse_args()
     if args.kill_after and not (args.async_ and args.checkpoint_dir):
         ap.error("--kill-after needs --async and --checkpoint-dir")
-    if args.mesh:
-        import jax
-        if jax.device_count() < args.mesh:
-            # the device-count flag must precede jax init: re-exec
-            env = dict(os.environ)
-            env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " "
-                                "--xla_force_host_platform_device_count="
-                                f"{args.mesh}").strip()
-            # the flag only multiplies CPU devices: pin the child to the cpu
-            # platform or (on a GPU host) it would see 1 device and re-exec
-            # forever
-            env.setdefault("JAX_PLATFORMS", "cpu")
-            raise SystemExit(subprocess.call(
-                [sys.executable, "-m", "repro.launch.join_serve",
-                 *sys.argv[1:]], env=env))
+    env = cpu_device_env(args.mesh) if args.mesh else None
+    if env is not None:
+        raise SystemExit(subprocess.call(
+            [sys.executable, "-m", "repro.launch.join_serve",
+             *sys.argv[1:]], env=env))
+    configure_compile_cache()
     if args.async_:
         run_async(tenants=args.tenants,
                   queries_per_tenant=args.queries_per_tenant,

@@ -11,16 +11,18 @@ Usage:
       --sub-rows 2048 --pushes 12
 
   # distributed: window stages span all mesh devices
-  PYTHONPATH=src python -m repro.launch.join_stream --mesh 8 --serve-mode psum
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.join_stream --mesh 8 \
+      --serve-mode psum
 
-``--mesh N`` re-execs under ``--xla_force_host_platform_device_count`` when
-the process has fewer than N devices (the flag must precede jax init).
+``--mesh N`` serves over the first N devices.  On the CPU
+(``JAX_PLATFORMS=cpu``) it re-execs under
+``--xla_force_host_platform_device_count`` when needed (the flag must precede
+jax init); on an accelerator a mesh larger than the host is an error.
 """
 
 from __future__ import annotations
 
 import argparse
-import os
 import subprocess
 import sys
 import time
@@ -29,6 +31,8 @@ from repro.core.budget import QueryBudget
 from repro.core.cost import CostModel
 from repro.core.window import WindowSpec
 from repro.data.synthetic import overlapping_relations
+from repro.launch.platform import (configure_compile_cache, cpu_device_env,
+                                   mesh_devices as _mesh_devices)
 from repro.runtime.stream_join import StreamJoinServer
 
 
@@ -37,10 +41,9 @@ def run(*, tenants: int = 2, pushes: int = 12, size: int = 4, slide: int = 1,
         serve_mode: str = "exact-parity", window_slots: int = 8) -> dict:
     mesh = None
     if mesh_devices:
-        import jax
         import numpy as np
         from jax.sharding import Mesh
-        mesh = Mesh(np.array(jax.devices()[:mesh_devices]), ("data",))
+        mesh = Mesh(np.array(_mesh_devices(mesh_devices)), ("data",))
     server = StreamJoinServer(batch_slots=max(tenants, 1), mesh=mesh,
                               serve_mode=serve_mode,
                               window_slots=window_slots,
@@ -107,17 +110,12 @@ def main() -> None:
     ap.add_argument("--serve-mode", default="exact-parity",
                     choices=["exact-parity", "psum"])
     args = ap.parse_args()
-    if args.mesh:
-        import jax
-        if jax.device_count() < args.mesh:
-            env = dict(os.environ)
-            env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " "
-                                "--xla_force_host_platform_device_count="
-                                f"{args.mesh}").strip()
-            env.setdefault("JAX_PLATFORMS", "cpu")
-            raise SystemExit(subprocess.call(
-                [sys.executable, "-m", "repro.launch.join_stream",
-                 *sys.argv[1:]], env=env))
+    env = cpu_device_env(args.mesh) if args.mesh else None
+    if env is not None:
+        raise SystemExit(subprocess.call(
+            [sys.executable, "-m", "repro.launch.join_stream",
+             *sys.argv[1:]], env=env))
+    configure_compile_cache()
     run(tenants=args.tenants, pushes=args.pushes, size=args.size,
         slide=args.slide, sub_rows=args.sub_rows,
         window_slots=args.window_slots, mesh_devices=args.mesh,

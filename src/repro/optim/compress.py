@@ -39,12 +39,10 @@ def ef_compress_grads(grads, error_buf, axis_names) -> tuple:
     format would be int8; XLA's collective sees the 2-byte payload — still
     2x, and the scale handling is exact).
     """
-    from repro.core.distributed import axis_size
-
     k = 1
     for a in (axis_names if isinstance(axis_names, (tuple, list))
               else (axis_names,)):
-        k *= axis_size(a)
+        k *= jax.lax.axis_size(a)
 
     def one(g, e):
         g = g.astype(jnp.float32) + e

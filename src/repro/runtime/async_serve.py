@@ -425,8 +425,7 @@ class AsyncJoinServer:
         """True when some shape class can fill every slot of its next
         batch — lingering past that point buys nothing."""
         counts = Counter(r._class for r in self.engine.queue)
-        return any(n >= self.engine._slot_cap(cls)
-                   for cls, n in counts.items())
+        return any(n >= self.engine.batch_slots for n in counts.values())
 
     def _earliest_deadline(self) -> float:
         return min((self.engine._deadline(r) for r in self.engine.queue),

@@ -6,7 +6,7 @@ carries relations (or a named dataset handle), a :class:`QueryBudget`, the
 aggregate/expression, and a tenant ``query_id``.  The engine:
 
 * **buckets** every relation to a power-of-two capacity
-  (:func:`repro.core.relation.bucket_to_pow2`) so queries fall into a small
+  (:func:`repro.core.relation.place_rows`) so queries fall into a small
   number of *shape classes*;
 * keeps a **compiled-executable cache** keyed by
   ``(stage, shape_class, batch)`` — repeat tenants never recompile.  Shape
@@ -119,8 +119,8 @@ from repro.core.join import (EXPRS, TUPLE_BYTES, JoinDiagnostics, JoinResult,
                              prepare_stage_kernels_batched, prepare_stage_pre,
                              sample_stage, sample_stage_kernels_batched)
 from repro.core.plan import CompiledPlan, Plan, compile_plan
-from repro.core.relation import (Relation, bucket_capacity, bucket_to_pow2,
-                                 fingerprint, shard_to_mesh)
+from repro.core.relation import (Relation, bucket_capacity, fingerprint,
+                                 place_rows, shard_to_mesh)
 from repro.runtime.telemetry import (NULL_TRACER, Histogram, MetricsRegistry,
                                      Tracer, latency_pcts, recon_pair,
                                      span_tree)
@@ -489,28 +489,25 @@ def _make_filter_build(num_blocks: int):
 # -- kernel-backed stage builders (Pallas grids own the slot dimension, so
 # -- these take the engine's slot-stacked batch directly instead of vmap) ---
 
-def _make_prepare_kernels(max_strata: int, interpret: bool):
+def _make_prepare_kernels(max_strata: int):
     def fn(rels, words, seeds):
-        return prepare_stage_kernels_batched(rels, words, max_strata, seeds,
-                                             interpret=interpret)
+        return prepare_stage_kernels_batched(rels, words, max_strata, seeds)
     return jax.jit(fn)
 
 
-def _make_sample_kernels(b_max: int, agg: str, confidence: float, expr: str,
-                         interpret: bool):
+def _make_sample_kernels(b_max: int, agg: str, confidence: float, expr: str):
     def fn(sorted_rels, strata, b_i, seeds):
         return sample_stage_kernels_batched(
             sorted_rels, strata, b_i, b_max, seeds, agg=agg,
-            confidence=confidence, expr=expr, interpret=interpret)
+            confidence=confidence, expr=expr)
     return jax.jit(fn)
 
 
-def _make_filter_build_kernels(num_blocks: int, interpret: bool):
+def _make_filter_build_kernels(num_blocks: int):
     from repro.kernels import ops as kops
 
     def fn(keys, valid, seed):
-        return kops.build_filter(keys, valid, num_blocks, seed,
-                                 interpret=interpret).words
+        return kops.build_filter(keys, valid, num_blocks, seed).words
     return jax.jit(fn)
 
 
@@ -627,10 +624,8 @@ class JoinServer:
     # -- admission ----------------------------------------------------------
 
     def _admit_rels(self, rels: Sequence[Relation]) -> list[Relation]:
-        rels = [bucket_to_pow2(r, minimum=self.mesh_k) for r in rels]
-        if self.mesh is not None:
-            rels = [shard_to_mesh(r, self.mesh, self.join_axes) for r in rels]
-        return rels
+        return [place_rows(r, bucket_capacity(r.capacity, self.mesh_k),
+                           self.mesh, self.join_axes) for r in rels]
 
     def register_dataset(self, name: str, rels: Sequence[Relation]) -> None:
         """Store a named (bucketed, mesh-sharded) dataset for handle queries.
@@ -845,11 +840,9 @@ class JoinServer:
                 partial(make_serve_filter_build, self.mesh, self.join_axes,
                         num_blocks=num_blocks))
         elif use_kernels:
-            from repro.kernels import ops as kops
             build, _ = self._executable(
                 "fbuild_k", (rel.capacity, num_blocks), None,
-                partial(_make_filter_build_kernels, num_blocks,
-                        kops.use_interpret()))
+                partial(_make_filter_build_kernels, num_blocks))
         else:
             build, _ = self._executable(
                 "fbuild", (rel.capacity, num_blocks), None,
@@ -883,29 +876,6 @@ class JoinServer:
         # admits the request (synchronously the two coincide)
         return req._ingest_t + req.budget.latency_s
 
-    def _slot_cap(self, cls: ShapeClass) -> int:
-        """Batch width cap for one step of this shape class.
-
-        Kernel classes stack per-slot filters and value arrays in VMEM, so
-        the per-slot working set divides the kernel budget: a class whose
-        single-query footprint was fine under the old per-query loop must
-        still serve — in narrower batches — rather than trip the wrappers'
-        stacked-layout asserts.  Floored to a power of two (batches pad to
-        their pow2 bucket, and pad slots occupy real VMEM slots too); at
-        1 the capacity is exactly the retired per-query path's.
-        """
-        if not cls.use_kernels:
-            return self.batch_slots
-        from repro.kernels import bloom_probe, edge_sample
-        filter_bytes = bloom.num_blocks_for(max(cls.caps), cls.fp_rate) \
-            * bloom.WORDS_PER_BLOCK * 4
-        values_bytes = max(cls.caps) * 4
-        cap = min(bloom_probe.VMEM_FILTER_LIMIT // filter_bytes,
-                  edge_sample.VMEM_VALUES_LIMIT // values_bytes,
-                  self.batch_slots)
-        cap = max(cap, 1)
-        return 1 << (cap.bit_length() - 1)          # floor to pow2
-
     def _take_batch(self) -> tuple:
         """Pick the next step's shape class and batch.
 
@@ -930,9 +900,8 @@ class JoinServer:
         if backlog:
             candidates.sort(key=self._deadline)   # stable: FIFO on ties
         batch, seen_ids = [], set()
-        slots = self._slot_cap(cls)
         for r in candidates:
-            if len(batch) == slots:
+            if len(batch) == self.batch_slots:
                 break
             if (self.sigma_pipeline and r.budget.error is not None
                     and r.query_id in seen_ids):
@@ -1365,21 +1334,18 @@ class JoinServer:
         compiled stage programs and two extra sample/exact arguments differ.
         """
         if cls.use_kernels:
-            from repro.kernels import ops as kops
-            interp = kops.use_interpret()
             # the fused Pallas sampler is two-way/non-dedup (the paper's hot
             # case); other kernel classes keep the kernel-backed prepare and
             # fall back to the vmapped jnp sampler — exactly approx_join's
             # own use_kernels composition, so bit-parity holds either way
             if cls.n_inputs == 2 and not cls.dedup:
                 sample = partial(_make_sample_kernels, cls.b_max, cls.agg,
-                                 cls.confidence, cls.expr, interp)
+                                 cls.confidence, cls.expr)
             else:
                 sample = partial(_make_sample, cls.b_max, cls.agg, cls.dedup,
                                  cls.confidence, cls.expr)
             return dict(
-                prepare=partial(_make_prepare_kernels, cls.max_strata,
-                                interp),
+                prepare=partial(_make_prepare_kernels, cls.max_strata),
                 sample=sample,
                 exact=partial(_make_exact, cls.agg, cls.expr),
                 sample_args=lambda prep, b, s: (prep.sorted_rels, prep.strata,

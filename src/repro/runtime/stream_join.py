@@ -75,7 +75,8 @@ import jax.numpy as jnp
 from repro.core import bloom
 from repro.core.budget import QueryBudget
 from repro.core.estimators import Estimate, SumParts, clt_finish, clt_sum_parts
-from repro.core.relation import Relation, bucket_capacity, fingerprint, pad_to
+from repro.core.relation import (Relation, bucket_capacity, concatenate,
+                                 fingerprint, pad_shards, place_rows)
 from repro.core.sampling import (Reservoir, reservoir_empty, reservoir_extend,
                                  reservoir_moments)
 from repro.core.window import SubWindow, WindowBuffer, WindowSpec
@@ -96,26 +97,19 @@ def _make_sketch():
     return jax.jit(reservoir_extend)
 
 
-def _make_window_assemble(n_subs: int, n_sides: int, cap: int):
+def _make_window_assemble(n_subs: int, n_sides: int, cap: int,
+                          num_shards: int):
     """One fused executable for window assembly: concat every side's
-    sub-window fields and pad to the window's capacity bucket (48 host-side
-    concatenates otherwise — measurable at streaming rates)."""
+    sub-windows and pad to the window's capacity bucket, per device row
+    block on a mesh (48 host-side concatenates otherwise — measurable at
+    streaming rates)."""
     def fn(flat):
-        rels = []
-        for side in range(n_sides):
-            cols = []
-            for f, fill in ((0, jnp.uint32(0)), (1, jnp.float32(0)),
-                            (2, False)):
-                parts = [flat[3 * (side * n_subs + m) + f]
-                         for m in range(n_subs)]
-                col = jnp.concatenate(parts)
-                pad = cap - col.shape[0]
-                if pad:
-                    col = jnp.concatenate(
-                        [col, jnp.full((pad,), fill, col.dtype)])
-                cols.append(col)
-            rels.append(Relation(*cols))
-        return rels
+        subs = [Relation(*flat[3 * i:3 * i + 3])
+                for i in range(n_sides * n_subs)]
+        return [pad_shards(concatenate(subs[side * n_subs:
+                                            (side + 1) * n_subs]),
+                           num_shards, cap)
+                for side in range(n_sides)]
     return jax.jit(fn)
 
 
@@ -255,12 +249,7 @@ class StreamJoinSession:
                 jnp.sum(r.valid[cap:].astype(jnp.int32))))
             self.server.stream_diagnostics.admission_dropped_rows += dropped
             r = Relation(r.keys[:cap], r.values[:cap], r.valid[:cap])
-        elif r.capacity < cap:
-            r = pad_to(r, cap)
-        if self.server.mesh is not None:
-            from repro.core.relation import shard_to_mesh
-            r = shard_to_mesh(r, self.server.mesh, self.server.join_axes)
-        return r
+        return place_rows(r, cap, self.server.mesh, self.server.join_axes)
 
     def push(self, rels: Sequence[Relation]) -> list[JoinRequest]:
         """Admit one micro-batch per side; returns the windows that became
@@ -336,7 +325,7 @@ class StreamJoinSession:
         asm, _ = self.server._executable(
             "wasm", (len(subs), self.n_sides, self.sub_cap, self.window_cap),
             None, partial(_make_window_assemble, len(subs), self.n_sides,
-                          self.window_cap))
+                          self.window_cap, self.server.mesh_k))
         flat = tuple(x for side in range(self.n_sides)
                      for s in subs for x in s.rels[side])
         return asm(flat)

@@ -3,7 +3,9 @@ unbiasedness, distributed-merge equivalence."""
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from conftest import hypothesis_or_stubs
+from repro.core.cost import CostModel, sizes_for_latency
 from repro.core.estimators import (HTParts, StratumStats, clt_avg,
                                    clt_avg_from, clt_count, clt_finish,
                                    clt_stdev, clt_stdev_from, clt_sum,
@@ -252,3 +254,24 @@ def test_avg_stdev_parts_merge_equals_direct():
                                float(s_whole.estimate), rtol=1e-5)
     np.testing.assert_allclose(float(s_merged.error_bound),
                                float(s_whole.error_bound), rtol=1e-4)
+
+
+@pytest.mark.xfail(strict=True, reason="one-draw strata report no variance "
+                   "(ROADMAP §3)")
+def test_single_draw_strata_report_a_bound():
+    """A latency budget that affords a small fraction draws once from
+    every stratum (``cost.sizes_for_latency``).  The estimate still varies
+    from sample to sample, so its 95% bound must not be zero."""
+    rng = np.random.default_rng(3)
+    pops = np.full(200, 15.0, np.float32)
+    b = sizes_for_latency(CostModel(beta_compute=1.0, epsilon=0.0), 30.0,
+                          0.0, jnp.asarray(pops))
+    assert (np.asarray(b) == 1.0).all()
+    vals = rng.normal(100.0, 30.0, size=(len(pops), 15))
+    pick = vals[np.arange(len(pops)), rng.integers(0, 15, len(pops))]
+    stats = StratumStats(jnp.ones(len(pops), bool), jnp.asarray(pops),
+                         jnp.asarray(b), jnp.asarray(pick, jnp.float32),
+                         jnp.asarray(pick ** 2, jnp.float32))
+    est = clt_sum(stats, 0.95)
+    assert float(est.estimate) != float(vals.sum())
+    assert float(est.error_bound) > 0

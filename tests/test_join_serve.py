@@ -376,33 +376,6 @@ def test_kernel_seed_sweep_no_recompiles_no_rebuilds(rng):
     assert all(q.done for q in qs)
 
 
-def test_kernel_batch_width_capped_by_vmem_budget(rng, monkeypatch):
-    """A kernel class whose per-slot VMEM working set only fits a few
-    stacked slots must serve in narrower batches (width 1 == exactly the
-    retired per-query path's capacity) instead of tripping the wrappers'
-    B * filter_bytes asserts — and each narrowed batch stays bit-identical
-    to per-query approx_join."""
-    from repro.core import bloom
-    from repro.kernels import bloom_probe
-    r1, r2 = make_pair(rng, n=1 << 11)
-    # shrink the budget so this class's stacked filters fit only 2 slots
-    fb = bloom.num_blocks_for(1 << 11, 0.01) * bloom.WORDS_PER_BLOCK * 4
-    monkeypatch.setattr(bloom_probe, "VMEM_FILTER_LIMIT", 2 * fb)
-    srv = JoinServer(batch_slots=4)
-    qs = [srv.submit(JoinRequest(rels=[r1, r2], budget=QueryBudget(error=0.5),
-                                 query_id=f"t{i}", seed=10 + i,
-                                 max_strata=512, b_max=256,
-                                 use_kernels=True))
-          for i in range(4)]
-    srv.run()
-    assert srv.diagnostics.max_batch == 2        # capped below batch_slots
-    assert srv.diagnostics.steps == 2
-    for i, q in enumerate(qs):
-        direct = approx_join([r1, r2], QueryBudget(error=0.5), max_strata=512,
-                             b_max=256, seed=10 + i, use_kernels=True)
-        assert _identical(q.result, direct), i
-
-
 def test_kernel_route_accepts_filter_seed_and_prebuilt_words(rng):
     """filter_seed decoupling (and prebuilt words) now work on the kernel
     path — the refactor lifted the old ValueError — and stay bit-identical

@@ -294,3 +294,108 @@ def test_shape_class_keys_on_mesh_shape(rng):
     assert isinstance(single, ShapeClass)
     # everything but the mesh key is identical
     assert single._replace(mesh=(("data", 8),)) == mesh8
+
+
+@pytest.mark.parametrize("n,k,capacity", [(1500, 4, 2048), (1500, 1, 2048),
+                                          (2048, 4, 2048), (5, 4, 8)])
+def test_pad_shards_spreads_real_rows_over_every_shard(n, k, capacity):
+    """Mesh admission pads each device's row block, not the tail: every
+    shard holds floor(n/k) or ceil(n/k) real rows (none holds only padding
+    when n >= k), and the real rows keep their order."""
+    import numpy as np
+    from repro.core.relation import pad_shards, relation
+    keys = np.arange(1, n + 1, dtype=np.uint32)
+    rel = pad_shards(relation(keys, keys.astype(np.float32)), k, capacity)
+    assert rel.capacity == capacity
+    valid = np.asarray(rel.valid)
+    per_shard = valid.reshape(k, -1).sum(axis=1)
+    assert per_shard.max() == -(-n // k) and per_shard.min() == n // k > 0
+    assert per_shard.sum() == n
+    np.testing.assert_array_equal(np.asarray(rel.keys)[valid], keys)
+    np.testing.assert_array_equal(np.asarray(rel.values)[valid],
+                                  keys.astype(np.float32))
+    assert not np.asarray(rel.keys)[~valid].any()
+
+
+@pytest.mark.parametrize("k", [4, 8])
+def test_stream_window_pads_every_shard(k):
+    """A stream on a k-device mesh: short micro-batches are admitted with
+    per-device padding (``pad_shards``, as ``place_rows`` does) and the
+    session's fused window assembly pads per device again, so a window of
+    three short sub-windows in a four-sub-window bucket still gives every
+    device real rows, in arrival order.  Tail padding would leave the last
+    device holding only padding."""
+    import numpy as np
+    from repro.core.relation import pad_shards, relation
+    from repro.core.window import SubWindow, window_relations
+    from repro.runtime.stream_join import _make_window_assemble
+    n_subs, sub_cap, rows = 3, 1024, 300
+    cap = 4 * sub_cap
+    mbs = [relation(np.arange(1 + m * rows, 1 + (m + 1) * rows,
+                              dtype=np.uint32)) for m in range(n_subs)]
+    subs = [SubWindow(m, (pad_shards(mb, k, sub_cap),) * 2, ("", ""))
+            for m, mb in enumerate(mbs)]
+    flat = tuple(x for side in range(2) for s in subs for x in s.rels[side])
+    got = _make_window_assemble(n_subs, 2, cap, k)(flat)
+    want = window_relations(subs, num_shards=k)
+    tail = window_relations(subs)
+    for g, w in zip(got, want):
+        for a, b in zip(g, w):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        valid = np.asarray(g.valid)
+        assert g.capacity == cap
+        assert (valid.reshape(k, -1).sum(axis=1) > 0).all()
+        np.testing.assert_array_equal(
+            np.asarray(g.keys)[valid], np.arange(1, 1 + n_subs * rows))
+    assert not np.asarray(tail[0].valid).reshape(k, -1)[-1].any()
+
+
+_ADMIT_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import numpy as np, jax
+from jax.sharding import Mesh
+from repro.core.relation import relation
+from repro.core.window import WindowSpec
+from repro.runtime.stream_join import StreamJoinServer
+
+def rows_per_device(rel):
+    shards = sorted(rel.valid.addressable_shards, key=lambda s: s.index)
+    return [int(np.asarray(s.data).sum()) for s in shards]
+
+def keys_in_order(rel, n):
+    valid = np.asarray(rel.valid)
+    np.testing.assert_array_equal(np.asarray(rel.keys)[valid],
+                                  np.arange(1, n + 1))
+
+srv = StreamJoinServer(batch_slots=2,
+                       mesh=Mesh(np.array(jax.devices()), ("data",)))
+n = 1500                                  # dataset: 1500 rows in a 2048 bucket
+ds = relation(np.arange(1, n + 1, dtype=np.uint32))
+for rel in srv._admit_rels([ds, ds]):
+    assert rel.capacity == 2048 and rows_per_device(rel) == [375] * 4
+    keys_in_order(rel, n)
+print("DATASET-OK")
+sess = srv.open_stream("s", WindowSpec(size=2, slide=2, sub_rows=1024))
+mb = relation(np.arange(1, 301, dtype=np.uint32))   # a short micro-batch
+got = sess._admit_micro_batch(mb)
+assert got.capacity == 1024 and rows_per_device(got) == [75] * 4
+keys_in_order(got, 300)
+print("STREAM-OK")
+"""
+
+
+def test_mesh_admission_gives_every_device_real_rows():
+    """On a 4-device mesh, dataset admission (``JoinServer._admit_rels``)
+    and stream admission (``StreamJoinSession._admit_micro_batch``) both
+    go through ``relation.place_rows``: every device holds an equal share
+    of the real rows, in order, even when the rows fill only part of the
+    capacity bucket."""
+    env = dict(os.environ, PYTHONPATH="src")
+    out = subprocess.run([sys.executable, "-c", _ADMIT_SCRIPT], env=env,
+                         capture_output=True, text=True, timeout=300,
+                         cwd=os.path.dirname(os.path.dirname(
+                             os.path.abspath(__file__))))
+    assert out.returncode == 0, out.stderr[-3000:]
+    for marker in ("DATASET-OK", "STREAM-OK"):
+        assert marker in out.stdout, (marker, out.stdout[-2000:])

@@ -260,7 +260,7 @@ def test_fused_window_assembly_matches_reference():
                                for r in _mb(800 + i)), ("", ""))
             for i in range(spec.size)]
     got = sess._window_rels(subs)
-    want = window_relations(subs, minimum=srv.mesh_k)
+    want = window_relations(subs, minimum=srv.mesh_k, num_shards=srv.mesh_k)
     for g, w in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g.keys), np.asarray(w.keys))
         np.testing.assert_array_equal(np.asarray(g.values),
