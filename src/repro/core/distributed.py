@@ -621,7 +621,9 @@ def distributed_approx_join(mesh: Mesh, rels: Sequence[Relation],
 # ---------------------------------------------------------------------------
 # Serving executables: batched (vmap over query slots) distributed stages,
 # one shard_map program per stage so the JoinServer's executable cache keys
-# (stage, shape_class, batch) work identically for both backends.
+# (stage, shape_class, batch) work identically for both backends.  Each
+# jitted function carries its stage's name, which the device trace gives
+# its executable (``jit_serve_exact_mesh(<hash>)``).
 # ---------------------------------------------------------------------------
 
 def _rel_specs(axes, n):
@@ -685,11 +687,11 @@ def make_serve_prepare(mesh: Mesh, axes: Sequence[str], *, n_rels: int,
                        out_specs=out_spec, check_vma=False)
 
     @jax.jit
-    def run(rels_b: Sequence[Relation], words_b, seeds):
+    def serve_prepare_mesh(rels_b: Sequence[Relation], words_b, seeds):
         flat = tuple(x for r in rels_b for x in (r.keys, r.values, r.valid))
         return fn(flat, words_b, seeds)
 
-    return run
+    return serve_prepare_mesh
 
 
 def make_serve_sample(mesh: Mesh, axes: Sequence[str], *, n_rels: int,
@@ -718,12 +720,13 @@ def make_serve_sample(mesh: Mesh, axes: Sequence[str], *, n_rels: int,
                        check_vma=False)
 
     @jax.jit
-    def run(sorted_rels, lstrata, mkeys, mvalid, b_merged, seeds):
+    def serve_sample_mesh(sorted_rels, lstrata, mkeys, mvalid, b_merged,
+                          seeds):
         flat = tuple(x for r in sorted_rels
                      for x in (r.keys, r.values, r.valid))
         return fn(flat, lstrata, mkeys, mvalid, b_merged, seeds)
 
-    return run
+    return serve_sample_mesh
 
 
 def make_serve_exact(mesh: Mesh, axes: Sequence[str], *, n_rels: int,
@@ -747,12 +750,12 @@ def make_serve_exact(mesh: Mesh, axes: Sequence[str], *, n_rels: int,
                        out_specs=(P(), P()), check_vma=False)
 
     @jax.jit
-    def run(sorted_rels, lstrata, mstrata):
+    def serve_exact_mesh(sorted_rels, lstrata, mstrata):
         flat = tuple(x for r in sorted_rels
                      for x in (r.keys, r.values, r.valid))
         return fn(flat, lstrata, mstrata)
 
-    return run
+    return serve_exact_mesh
 
 
 def make_serve_sample_psum(mesh: Mesh, axes: Sequence[str], *, n_rels: int,
@@ -789,12 +792,12 @@ def make_serve_sample_psum(mesh: Mesh, axes: Sequence[str], *, n_rels: int,
                        check_vma=False)
 
     @jax.jit
-    def run(sorted_rels, lstrata, b, seeds):
+    def serve_sample_psum(sorted_rels, lstrata, b, seeds):
         flat = tuple(x for r in sorted_rels
                      for x in (r.keys, r.values, r.valid))
         return fn(flat, lstrata, b, seeds)
 
-    return run
+    return serve_sample_psum
 
 
 def make_serve_exact_psum(mesh: Mesh, axes: Sequence[str], *, n_rels: int,
@@ -817,12 +820,12 @@ def make_serve_exact_psum(mesh: Mesh, axes: Sequence[str], *, n_rels: int,
                        out_specs=(P(), P()), check_vma=False)
 
     @jax.jit
-    def run(sorted_rels, lstrata):
+    def serve_exact_psum(sorted_rels, lstrata):
         flat = tuple(x for r in sorted_rels
                      for x in (r.keys, r.values, r.valid))
         return fn(flat, lstrata)
 
-    return run
+    return serve_exact_psum
 
 
 def make_serve_filter_build(mesh: Mesh, axes: Sequence[str], *,
@@ -835,10 +838,11 @@ def make_serve_filter_build(mesh: Mesh, axes: Sequence[str], *,
     """
     axes = tuple(axes)
 
-    def build(keys, valid, seed):
+    def serve_filter_build_mesh(keys, valid, seed):
         return or_reduce(bloom.build(keys, valid, num_blocks, seed).words,
                          axes)
 
-    fn = jax.shard_map(build, mesh=mesh, in_specs=(P(axes), P(axes), P()),
-                       out_specs=P(), check_vma=False)
+    fn = jax.shard_map(serve_filter_build_mesh, mesh=mesh,
+                       in_specs=(P(axes), P(axes), P()), out_specs=P(),
+                       check_vma=False)
     return jax.jit(fn)

@@ -94,6 +94,7 @@ def build_join_filter(rels: Sequence[Relation], num_blocks: int,
     return bloom.intersect_all(filters)
 
 
+@jax.named_scope("filter_probe")
 def filter_relations(rels: Sequence[Relation],
                      join_filter: bloom.BloomFilter) -> list[Relation]:
     """Probe + discard (the shuffle-avoidance step)."""
@@ -130,7 +131,8 @@ def _prepare_tail(live: Sequence[Relation], rels: Sequence[Relation],
     """Shared sort/group-by tail of every prepare variant (jnp and kernel,
     single and batched) — one copy, so the bit-parity contract between the
     variants cannot drift."""
-    sorted_rels = [sort_by_key(r) for r in live]
+    with jax.named_scope("sort"):
+        sorted_rels = [sort_by_key(r) for r in live]
     strata = build_strata(sorted_rels, max_strata)
     return PrepareOut(sorted_rels, strata,
                       jnp.stack([r.count() for r in live]),
@@ -146,8 +148,10 @@ def prepare_stage(rels: Sequence[Relation], num_blocks: int, max_strata: int,
     :func:`bloom.intersect_all` checks seed equality only on concrete ints,
     so the cascaded AND-merge routes through it on tracers too.
     """
-    filters = [bloom.build(r.keys, r.valid, num_blocks, seed) for r in rels]
-    join_filter = bloom.intersect_all(filters)
+    with jax.named_scope("filter_probe"):
+        filters = [bloom.build(r.keys, r.valid, num_blocks, seed)
+                   for r in rels]
+        join_filter = bloom.intersect_all(filters)
     return _prepare_tail(filter_relations(rels, join_filter), rels,
                          max_strata)
 
@@ -166,9 +170,10 @@ def prepare_stage_pre(rels: Sequence[Relation], filter_words: jnp.ndarray,
         raise ValueError(
             f"prepare_stage_pre: {filter_words.shape[0]} prebuilt filters "
             f"for {len(rels)} inputs")
-    join_filter = bloom.intersect_all(
-        [bloom.BloomFilter(filter_words[i], seed)
-         for i in range(filter_words.shape[0])])
+    with jax.named_scope("filter_probe"):
+        join_filter = bloom.intersect_all(
+            [bloom.BloomFilter(filter_words[i], seed)
+             for i in range(filter_words.shape[0])])
     return _prepare_tail(filter_relations(rels, join_filter), rels,
                          max_strata)
 
@@ -189,22 +194,23 @@ def prepare_stage_kernels(rels: Sequence[Relation], num_blocks: int,
     uint32 hash math (asserted in ``tests/test_kernels.py``).
     """
     from repro.kernels import ops as kops
-    if filter_words is None:
-        words = bloom.intersect_all(
-            [kops.build_filter(r.keys, r.valid, num_blocks, seed,
-                               interpret=interpret) for r in rels]).words
-    else:
-        if filter_words.shape[0] != len(rels):
-            raise ValueError(
-                f"prepare_stage_kernels: {filter_words.shape[0]} prebuilt "
-                f"filters for {len(rels)} inputs")
-        words = bloom.intersect_all(
-            [bloom.BloomFilter(filter_words[i], seed)
-             for i in range(filter_words.shape[0])]).words
-    live = [Relation(r.keys, r.values,
-                     r.valid & kops.probe_filter(words, r.keys, seed,
-                                                 interpret=interpret))
-            for r in rels]
+    if filter_words is not None and filter_words.shape[0] != len(rels):
+        raise ValueError(
+            f"prepare_stage_kernels: {filter_words.shape[0]} prebuilt "
+            f"filters for {len(rels)} inputs")
+    with jax.named_scope("filter_probe"):
+        if filter_words is None:
+            words = bloom.intersect_all(
+                [kops.build_filter(r.keys, r.valid, num_blocks, seed,
+                                   interpret=interpret) for r in rels]).words
+        else:
+            words = bloom.intersect_all(
+                [bloom.BloomFilter(filter_words[i], seed)
+                 for i in range(filter_words.shape[0])]).words
+        live = [Relation(r.keys, r.values,
+                         r.valid & kops.probe_filter(words, r.keys, seed,
+                                                     interpret=interpret))
+                for r in rels]
     return _prepare_tail(live, rels, max_strata)
 
 
@@ -228,13 +234,14 @@ def prepare_stage_kernels_batched(rels: Sequence[Relation],
         raise ValueError(
             f"prepare_stage_kernels_batched: {filter_words.shape[1]} "
             f"prebuilt filters for {len(rels)} inputs")
-    jwords = bloom.intersect_all(
-        [bloom.BloomFilter(filter_words[:, i], seeds)
-         for i in range(filter_words.shape[1])]).words
-    live = [Relation(r.keys, r.values,
-                     r.valid & kops.probe_filter_batched(
-                         jwords, r.keys, seeds, interpret=interpret))
-            for r in rels]
+    with jax.named_scope("filter_probe"):
+        jwords = bloom.intersect_all(
+            [bloom.BloomFilter(filter_words[:, i], seeds)
+             for i in range(filter_words.shape[1])]).words
+        live = [Relation(r.keys, r.values,
+                         r.valid & kops.probe_filter_batched(
+                             jwords, r.keys, seeds, interpret=interpret))
+                for r in rels]
     return jax.vmap(
         lambda live_i, rels_i: _prepare_tail(live_i, rels_i, max_strata))(
         live, list(rels))
@@ -321,8 +328,9 @@ def sample_stage_kernels(sorted_rels: Sequence[Relation], strata: Strata,
     """Kernel-backed :func:`sample_stage` (two-way, non-dedup): the fused
     draw->gather->f->reduce Pallas sampler + the shared estimate stage."""
     from repro.kernels import ops as kops
-    stats = kops.sample_stats(sorted_rels, strata, b_i, b_max, seed, expr,
-                              interpret=interpret)
+    with jax.named_scope("sampler"):
+        stats = kops.sample_stats(sorted_rels, strata, b_i, b_max, seed,
+                                  expr, interpret=interpret)
     value, err, cnt, dof = estimate_stage(
         _kernel_sample_result(stats), agg=agg, dedup=False,
         confidence=confidence)
@@ -351,10 +359,11 @@ def sample_stage_kernels_batched(sorted_rels: Sequence[Relation],
         joinable,
         jnp.prod(jnp.maximum(strata.counts, 0).astype(jnp.float32), axis=1),
         0.0)
-    stats = kops.sample_stats_batched(
-        sorted_rels[0].values, sorted_rels[1].values,
-        strata.keys, strata.starts, strata.counts, joinable, population,
-        b_i, seeds, b_max, expr, interpret=interpret)
+    with jax.named_scope("sampler"):
+        stats = kops.sample_stats_batched(
+            sorted_rels[0].values, sorted_rels[1].values,
+            strata.keys, strata.starts, strata.counts, joinable, population,
+            b_i, seeds, b_max, expr, interpret=interpret)
     value, err, cnt, dof = jax.vmap(
         lambda s: estimate_stage(_kernel_sample_result(s), agg=agg,
                                  dedup=False, confidence=confidence))(stats)

@@ -69,6 +69,7 @@ def _segment(sorted_keys: jnp.ndarray, stratum_keys: jnp.ndarray):
     return start.astype(jnp.int32), (end - start).astype(jnp.int32)
 
 
+@jax.named_scope("strata")
 def build_strata(sorted_rels: Sequence[Relation], max_strata: int) -> Strata:
     """Identify strata from sorted_rels[0]; locate segments in every side.
 
@@ -148,6 +149,7 @@ def default_f(values: Sequence[jnp.ndarray]) -> jnp.ndarray:
     return out
 
 
+@jax.named_scope("sampler")
 def sample_edges(sorted_rels: Sequence[Relation], strata: Strata,
                  b_i: jnp.ndarray, b_max: int, seed,
                  f: Callable[[Sequence[jnp.ndarray]], jnp.ndarray]
@@ -197,6 +199,7 @@ def sample_edges(sorted_rels: Sequence[Relation], strata: Strata,
 # given and the overlap is large.
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("exact_sum")
 def per_stratum_value_sums(sorted_rels, strata) -> jnp.ndarray:
     """[n_sides, S] sum of values per stratum per side.
 
@@ -222,6 +225,7 @@ def per_stratum_value_sums(sorted_rels, strata) -> jnp.ndarray:
 _per_stratum_value_sums = per_stratum_value_sums
 
 
+@jax.named_scope("exact_sum")
 def exact_sum_of_sums_from(S_k: jnp.ndarray, strata: Strata) -> jnp.ndarray:
     """Finish SUM(v_1 + ... + v_n) from per-stratum value sums [n, S].
 
@@ -247,6 +251,7 @@ def exact_sum_of_sums_from(S_k: jnp.ndarray, strata: Strata) -> jnp.ndarray:
     return jnp.sum(jnp.where(strata.joinable, per_stratum, 0.0))
 
 
+@jax.named_scope("exact_sum")
 def exact_sum_of_products_from(S_k: jnp.ndarray,
                                strata: Strata) -> jnp.ndarray:
     """Finish SUM(v_1 * ... * v_n) from per-stratum value sums [n, S]."""

@@ -75,6 +75,7 @@ def bloom_hashes_batched(keys: jnp.ndarray, seeds: jnp.ndarray,
         out_shape=[jax.ShapeDtypeStruct((B, rows, LANES), jnp.int32),
                    jax.ShapeDtypeStruct((B, W, rows, LANES), jnp.uint32)],
         interpret=use_interpret(interpret),
+        name="bloom_build_hashes",
     )(seeds, key_tiles(keys, block))
     return blk.reshape(B, n), masks.reshape(B, W, n).transpose(0, 2, 1)
 

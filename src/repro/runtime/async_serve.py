@@ -70,7 +70,7 @@ from repro.runtime.fault import (Heartbeat, InjectedFault,
                                  elastic_restore_engine, guarded_step)
 from repro.runtime.join_serve import JoinRequest, JoinServer, tenant_of
 from repro.runtime.stream_join import StreamJoinServer, StreamJoinSession
-from repro.runtime.telemetry import NULL_TRACER, Tracer
+from repro.runtime.telemetry import NULL_TRACER, GcSpans, Tracer
 
 DEFAULT_LINGER_S = 0.002
 
@@ -140,6 +140,11 @@ class AsyncJoinServer:
         self._running = True
         self._in_linger = False
         self._steal_wanted = threading.Event()
+        # traced only: (start, end) of the idle period in progress, and the
+        # hook that puts every garbage collection on this replica's lane
+        self._idle: Optional[tuple] = None
+        self._gc = GcSpans(self.tracer, name) if self.tracer.enabled \
+            else None
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name=f"async-join-{name}")
         self._thread.start()
@@ -262,6 +267,9 @@ class AsyncJoinServer:
             self._running = False
             self._cv.notify_all()
         self._thread.join(timeout)
+        if self._gc is not None:
+            self._gc.close()
+            self._gc = None
         if self._ckpt_writer is not None:
             self._ckpt_writer.join(timeout)
         self._fail_pending(RuntimeError(f"AsyncJoinServer {self.name} "
@@ -295,17 +303,25 @@ class AsyncJoinServer:
                     # back-to-back (drain -> linger -> step), so yield for a
                     # moment or the steal can never win the reacquire race
                     time.sleep(0.001)
-                self._drain()
+                if self.tracer.enabled and self._ingress:
+                    with self.tracer.span("drain", cat="loop",
+                                          tid=self.name):
+                        self._drain()
+                else:
+                    self._drain()
                 self._maybe_checkpoint()
                 if not self.engine.queue:
                     if self._front is not None:
                         self._front.maybe_failover(blocking=False)
                         if self._front._steal_for(self):
                             continue
-                    with self._cv:
-                        if self._running and not self._ingress:
-                            self._cv.wait(self.idle_wait_s)
+                    self._wait_idle()
                     continue
+                if self._idle is not None:
+                    t0, t1 = self._idle
+                    self._idle = None
+                    self.tracer.event("idle", t0, t1 - t0, cat="loop",
+                                      tid=self.name)
                 if self.tracer.enabled:
                     with self.tracer.span("linger", cat="batch",
                                           tid=self.name,
@@ -330,6 +346,22 @@ class AsyncJoinServer:
         except BaseException as e:  # noqa: BLE001 — fail futures, don't hang
             self.error = e
             self._fail_pending(e)
+
+    def _wait_idle(self) -> None:
+        """Wait for ingress while the engine queue is empty.  Traced, the
+        waits of one idle period make one ``idle`` span, from the first
+        wait's start to the last one's end, recorded when work arrives: an
+        idle server adds one event per idle period, not one per wake-up."""
+        with self._cv:
+            if not self._running or self._ingress:
+                return
+            if not self.tracer.enabled:
+                self._cv.wait(self.idle_wait_s)
+                return
+            t0 = time.perf_counter()
+            self._cv.wait(self.idle_wait_s)
+            start = t0 if self._idle is None else self._idle[0]
+            self._idle = (start, time.perf_counter())
 
     def _maybe_checkpoint(self) -> None:
         """Checkpoint the engine if state changed and the cadence allows.

@@ -97,7 +97,7 @@ from __future__ import annotations
 import time
 from collections import OrderedDict
 from dataclasses import dataclass, field
-from functools import partial
+from functools import cache, partial
 from typing import NamedTuple, Optional, Sequence
 
 import jax
@@ -129,6 +129,10 @@ from repro.runtime.telemetry import reconciliation_report as _recon_report
 DEFAULT_B_MAX = 2048
 AGGS = ("sum", "count", "avg", "stdev")
 SERVE_MODES = ("exact-parity", "psum")
+# a traced step's host phases (name -> span category): engine-lane spans
+# between and after the stage dispatches, none of them any one query's
+_HOST_PHASES = {"inputs": "engine", "decide": "engine", "finish": "engine",
+                "tracer": "tracer"}
 
 
 def tenant_of(query_id: str) -> str:
@@ -458,57 +462,94 @@ def shape_class_of(req: JoinRequest, mesh_shape: tuple = (),
                       serve_mode, bucket_cap)
 
 
+# Each stage function carries its stage's name: the device trace names an
+# executable after it (``jit_serve_prepare(<hash>)`` on the XLA Modules line).
+
 def _make_prepare(max_strata: int):
-    def fn(rels, words, seed):
+    def serve_prepare(rels, words, seed):
         return prepare_stage_pre(rels, words, max_strata, seed)
-    return jax.jit(jax.vmap(fn))
+    return jax.jit(jax.vmap(serve_prepare))
 
 
 def _make_sample(b_max: int, agg: str, dedup: bool, confidence: float,
                  expr: str):
     f_fn = EXPRS[expr][0]
-    def fn(sorted_rels, strata, b_i, seed):
+    def serve_sample(sorted_rels, strata, b_i, seed):
         return sample_stage(sorted_rels, strata, b_i, b_max, seed,
                             agg=agg, dedup=dedup, confidence=confidence,
                             f_fn=f_fn)
-    return jax.jit(jax.vmap(fn))
+    return jax.jit(jax.vmap(serve_sample))
 
 
 def _make_exact(agg: str, expr: str):
-    def fn(sorted_rels, strata):
+    def serve_exact(sorted_rels, strata):
         return exact_stage(sorted_rels, strata, agg=agg, expr=expr)
-    return jax.jit(jax.vmap(fn))
+    return jax.jit(jax.vmap(serve_exact))
 
 
 def _make_filter_build(num_blocks: int):
-    def fn(keys, valid, seed):
+    def serve_filter_build(keys, valid, seed):
         return bloom.build(keys, valid, num_blocks, seed).words
-    return jax.jit(fn)
+    return jax.jit(serve_filter_build)
 
 
 # -- kernel-backed stage builders (Pallas grids own the slot dimension, so
 # -- these take the engine's slot-stacked batch directly instead of vmap) ---
 
 def _make_prepare_kernels(max_strata: int):
-    def fn(rels, words, seeds):
+    def serve_prepare_kernels(rels, words, seeds):
         return prepare_stage_kernels_batched(rels, words, max_strata, seeds)
-    return jax.jit(fn)
+    return jax.jit(serve_prepare_kernels)
 
 
 def _make_sample_kernels(b_max: int, agg: str, confidence: float, expr: str):
-    def fn(sorted_rels, strata, b_i, seeds):
+    def serve_sample_kernels(sorted_rels, strata, b_i, seeds):
         return sample_stage_kernels_batched(
             sorted_rels, strata, b_i, b_max, seeds, agg=agg,
             confidence=confidence, expr=expr)
-    return jax.jit(fn)
+    return jax.jit(serve_sample_kernels)
 
 
 def _make_filter_build_kernels(num_blocks: int):
     from repro.kernels import ops as kops
 
-    def fn(keys, valid, seed):
+    def serve_filter_build_kernels(keys, valid, seed):
         return kops.build_filter(keys, valid, num_blocks, seed).words
-    return jax.jit(fn)
+    return jax.jit(serve_filter_build_kernels)
+
+
+def _metered_pairs(fetch, i: int, fe_model: float, wire: float, k: int,
+                   bytes_model: Optional[dict]) -> dict:
+    """Slot ``i``'s reconciliation pairs from the step's meters (``fetch()``
+    -> host ``(live_counts, shuffled_tuple_bytes, device_shuffled_bytes)``,
+    the last two ``None`` off the mesh)."""
+    live, tup, dev = fetch()
+    live_model = float(np.asarray(live[i]).sum()) * TUPLE_BYTES
+    # live-tuple bytes: §3.1's filtered-shuffle volume vs the metered
+    # per-query tuple bytes actually moved (mesh only — single-device and
+    # kernel queries move no wire tuples)
+    pairs = [recon_pair("live_tuple_bytes", live_model,
+                        None if tup is None else float(tup[i]))]
+    # per-query filter exchange is modeled-only here: the measured
+    # counterpart is cumulative and amortized across the word cache (see
+    # the server-level pair in reconciliation_report)
+    pairs.append(recon_pair("filter_exchange_bytes", fe_model, None))
+    if tup is not None:
+        # static collective-buffer model vs live tuple bytes: the gap is
+        # the dense dataflow's buffer slack
+        pairs.append(recon_pair("dist_wire_bytes_model", wire,
+                                float(tup[i])))
+    if bytes_model is not None:
+        # compile-time plan-node model vs this execution's serve-time
+        # restatement of the same §3.1 cost
+        pairs.append(recon_pair("node_bytes_model",
+                                float(bytes_model["bytes_pushdown"]),
+                                live_model + fe_model))
+    out = {"pairs": pairs}
+    if dev is not None:
+        out["per_device"] = {"modeled": [wire / k] * k,
+                             "measured": [float(x) for x in dev[i]]}
+    return out
 
 
 class JoinServer:
@@ -940,7 +981,11 @@ class JoinServer:
                 d.shuffled_bytes_repartition - d.shuffled_bytes_filtered)
             self._notify_done(req)
         if self.tracer.enabled:
-            self._trace_step(cls, batch, t_form, t_dispatch, t_done)
+            t_end = time.perf_counter()
+            with self.tracer.span("tracer", cat="tracer",
+                                  tid=self.trace_name):
+                self._trace_step(cls, batch, t_form, t_dispatch, t_done,
+                                 t_end)
         self._stage_trace = self._recon_batch = None
         return len(batch)
 
@@ -953,22 +998,31 @@ class JoinServer:
         return "single"
 
     def _trace_step(self, cls: ShapeClass, batch: list[JoinRequest],
-                    t_form: float, t_dispatch: float, t_done: float) -> None:
+                    t_form: float, t_dispatch: float, t_done: float,
+                    t_end: float) -> None:
         """Emit the step's spans: one engine-lane group (batch-formation,
-        step, stage timings) plus a complete per-query span tree (query ->
-        queued/execute -> prepare/filter-exchange/shuffle/sample|exact ->
-        complete) on a lane per request instance, and the per-query byte
-        reconciliation records collected by ``_run_batch``."""
+        step, its host phases and stage timings, complete) plus a complete
+        per-query span tree (query -> queued/execute ->
+        prepare/filter-exchange/shuffle/sample|exact -> complete) on a lane
+        per request instance, and the per-query byte reconciliation records
+        collected by ``_run_batch``."""
         tr, lane, path = self.tracer, self.trace_name, self._path_of(cls)
         tr.event("batch-formation", t_form, t_dispatch - t_form, cat="batch",
                  tid=lane, batch=len(batch), path=path)
         tr.event("step", t_dispatch, t_done - t_dispatch, cat="serve",
                  tid=lane, batch=len(batch), path=path)
         stages = self._stage_trace or {}
+        if "finish" in stages:
+            ts, _, extra = stages["finish"]
+            stages["finish"] = (ts, t_done - ts, extra)
         for name, (ts, dur, extra) in stages.items():
-            tr.event(name, ts, dur, cat="stage", tid=lane, path=path,
-                     **extra)
-        recs = self._recon_batch or {}
+            tr.event(name, ts, dur, cat=_HOST_PHASES.get(name, "stage"),
+                     tid=lane, path=path, **extra)
+        # per-request bookkeeping after the step: latencies, the result
+        # diagnostics' reads, completion futures and their callbacks
+        tr.event("complete", t_done, t_end - t_done, cat="engine", tid=lane,
+                 batch=len(batch), path=path)
+        fe_model, recs = self._recon_batch or (0.0, {})
         for req in batch:
             tid = f"q:{req.query_id}#{req._span_id}"
             base = dict(query_id=req.query_id, qspan=req._span_id, path=path)
@@ -986,27 +1040,22 @@ class JoinServer:
                      req._complete_t - req._dispatch_t, cat="query", tid=tid,
                      **base)
             for name, (ts, dur, extra) in stages.items():
-                tr.event(name, ts, dur, cat="stage", tid=tid, **base,
-                         **extra)
+                if name not in _HOST_PHASES:
+                    tr.event(name, ts, dur, cat="stage", tid=tid, **base,
+                             **extra)
             rec = recs.get(id(req))
             if rec is not None:
                 tr.note_recon(rec)
-                # zero-duration sub-phase markers carrying the byte pairs
-                # (filter exchange and shuffle are fused into the prepare
-                # dispatch — one XLA program — so they mark, not span)
+                # zero-duration sub-phase markers (filter exchange and
+                # shuffle are fused into the prepare dispatch — one XLA
+                # program — so they mark, not span); the shuffle's metered
+                # bytes stay on the device until reconciliation_report
                 p_ts, p_dur, _ = stages.get("prepare",
                                             (req._dispatch_t, 0.0, None))
-                pairs = {p["name"]: p for p in rec["pairs"]}
-                fe = pairs.get("filter_exchange_bytes")
-                if fe is not None:
-                    tr.event("filter-exchange", p_ts + p_dur, 0.0,
-                             cat="stage", tid=tid, modeled=fe["modeled"],
-                             **base)
-                sh = pairs.get("live_tuple_bytes")
-                if sh is not None:
-                    tr.event("shuffle", p_ts + p_dur, 0.0, cat="stage",
-                             tid=tid, modeled=sh["modeled"],
-                             measured=sh["measured"], **base)
+                tr.event("filter-exchange", p_ts + p_dur, 0.0, cat="stage",
+                         tid=tid, modeled=fe_model, **base)
+                tr.event("shuffle", p_ts + p_dur, 0.0, cat="stage",
+                         tid=tid, **base)
             tr.instant("complete", cat="query", tid=tid,
                        ts=req._complete_t, **base)
 
@@ -1417,16 +1466,27 @@ class JoinServer:
 
     def _run_batch(self, cls: ShapeClass, batch: list[JoinRequest]) -> None:
         """One engine step — single fused dispatch per stage; with a mesh,
-        each dispatch spans all devices through the shard_map pipeline."""
+        each dispatch spans all devices through the shard_map pipeline.
+
+        Traced, the step is tiled by spans in order: ``inputs`` (stacking,
+        stage builders, executable lookup), ``compile`` (a fresh shape
+        class only), ``prepare``, ``decide`` (strata fetch, sample sizes),
+        ``sample``/``exact``, ``tracer`` (its own reconciliation records)
+        and ``finish`` (result assembly, sigma feedback, meters, and
+        releasing the step's arrays on the way out)."""
+        # stage-timing scratch for the tracer ({} only while tracing, so the
+        # untraced path keeps its exact laziness — no extra blocking, and no
+        # clock read it does not need)
+        stages = {} if self.tracer.enabled else None
+        t_in = time.perf_counter() if stages is not None else 0.0
         B, rels_b, words_b, seeds, fseeds, num_blocks = \
             self._batch_inputs(cls, batch)
         builders = self._stage_builders(cls, num_blocks)
-        # stage-timing scratch for the tracer ({} only while tracing, so the
-        # untraced path keeps its exact laziness — no extra blocking)
-        stages = {} if self.tracer.enabled else None
 
         prepare, fresh = self._executable("prepare", cls, B,
                                           builders["prepare"])
+        if stages is not None:
+            stages["inputs"] = (t_in, time.perf_counter() - t_in, {})
         if fresh:
             # warm the executable off the clock: d_filter feeds the latency
             # cost function (§3.2), which models repeated query execution —
@@ -1441,7 +1501,8 @@ class JoinServer:
         t0 = time.perf_counter()
         prep = prepare(rels_b, words_b, fseeds)
         jax.block_until_ready(prep.strata.counts)
-        d_filter = time.perf_counter() - t0
+        t_prep = time.perf_counter()
+        d_filter = t_prep - t0
         self.diagnostics.filter_s += d_filter
         if stages is not None:
             stages["prepare"] = (t0, d_filter, {})
@@ -1456,6 +1517,7 @@ class JoinServer:
             cls, batch, B, population, skeys, slice_i, d_filter)
 
         # -- fused device dispatches (per stage, whole batch) ---------------
+        # (``decide`` runs from prepare's wait to the first of them)
         value = err = cnt = dof = stats = e_est = e_cnt = None
         if sampled_idx:
             sample, _ = self._executable("sample", cls, B,
@@ -1464,6 +1526,7 @@ class JoinServer:
             value, err, cnt, dof, stats = sample(*builders["sample_args"](
                 prep, jnp.stack(b_rows), seeds + jnp.uint32(1)))
             if stages is not None:
+                stages.setdefault("decide", (t_prep, ts - t_prep, {}))
                 jax.block_until_ready(value)
                 stages["sample"] = (ts, time.perf_counter() - ts,
                                     {"queries": len(sampled_idx)})
@@ -1472,6 +1535,7 @@ class JoinServer:
             ts = time.perf_counter()
             e_est, e_cnt = exact(*builders["exact_args"](prep))
             if stages is not None:
+                stages.setdefault("decide", (t_prep, ts - t_prep, {}))
                 jax.block_until_ready(e_est)
                 stages["exact"] = (ts, time.perf_counter() - ts,
                                    {"queries": len(exact_idx)})
@@ -1479,19 +1543,27 @@ class JoinServer:
         # kernel classes run the single-device pipeline even on a mesh
         # server (plain PrepareOut: no shuffle buckets, nothing dropped)
         meshless = self.mesh is None or cls.use_kernels
+        fbytes = num_blocks * bloom.WORDS_PER_BLOCK * 4
+        if stages is not None:
+            t_rec = time.perf_counter()
+            self._recon_batch = self._recon_records(cls, batch, prep,
+                                                    fbytes, meshless)
+            t_fin = time.perf_counter()
+            stages["tracer"] = (t_rec, t_fin - t_rec, {})
+            # finish runs to the step's end, which step() stamps
+            stages["finish"] = (t_fin, 0.0, {})
+            self._stage_trace = stages
         if cls.use_kernels:
             self.diagnostics.kernel_queries += len(batch)
         dropped = None if meshless else np.asarray(
             jax.device_get(prep.bucket_overflow), np.float64)
         self._finish_batch(
             batch, strata_slice=slice_i, live_counts=prep.live_counts,
-            total_counts=prep.total_counts,
-            fbytes=num_blocks * bloom.WORDS_PER_BLOCK * 4, d_filter=d_filter,
+            total_counts=prep.total_counts, fbytes=fbytes, d_filter=d_filter,
             exact_idx=exact_idx, e_est=e_est, e_cnt=e_cnt, value=value,
             err=err, cnt=cnt, dof=dof, stats=stats, skeys=skeys,
             dropped=dropped)
 
-        fbytes = num_blocks * bloom.WORDS_PER_BLOCK * 4
         self.diagnostics.filter_exchange_bytes_model += \
             len(batch) * float(filter_exchange_bytes(cls.n_inputs, fbytes))
         if not meshless:
@@ -1513,60 +1585,32 @@ class JoinServer:
                 np.float64)[:n_real].sum(axis=0)
             self.diagnostics.dist_wire_bytes_model += \
                 n_real * self._wire_bytes_model(cls)
-        if stages is not None:
-            self._stage_trace = stages
-            self._recon_batch = self._recon_records(cls, batch, prep,
-                                                    fbytes, meshless)
 
     def _recon_records(self, cls: ShapeClass, batch: list[JoinRequest],
-                       prep, fbytes: int, meshless: bool) -> dict:
+                       prep, fbytes: int, meshless: bool) -> tuple:
         """Per-query byte-reconciliation records (traced steps only): each
         modeled cost paired with its metered counterpart, keyed by request
-        identity for ``_trace_step``.  The extra device_gets here run only
-        under tracing — the untraced hot path is unchanged."""
-        n_real, n, k = len(batch), cls.n_inputs, self.mesh_k
-        live = np.asarray(jax.device_get(prep.live_counts))[:n_real]
-        tup = dev = None
-        if not meshless:
-            tup = np.asarray(jax.device_get(
-                prep.shuffled_tuple_bytes))[:n_real]
-            dev = np.asarray(jax.device_get(
-                prep.device_shuffled_bytes))[:n_real]
+        identity for ``_trace_step``; returned with the per-query filter
+        exchange model.  The meters stay device arrays: each record's
+        ``meter`` reads them when a report is built (``resolve_recon``), so
+        a traced step waits on the device no more than an untraced one."""
+        n, k = cls.n_inputs, self.mesh_k
+        meters = (prep.live_counts, None, None) if meshless else (
+            prep.live_counts, prep.shuffled_tuple_bytes,
+            prep.device_shuffled_bytes)
+        # one host read per step, shared by the step's records
+        fetch = cache(partial(jax.device_get, meters))
         path, wire = self._path_of(cls), self._wire_bytes_model(cls)
         fe_model = float(filter_exchange_bytes(n, fbytes))
         out = {}
         for i, req in enumerate(batch):
-            live_model = float(live[i].sum()) * TUPLE_BYTES
-            # live-tuple bytes: §3.1's filtered-shuffle volume vs the
-            # metered per-query tuple bytes actually moved (mesh only —
-            # single-device and kernel queries move no wire tuples)
-            pairs = [recon_pair("live_tuple_bytes", live_model,
-                                None if tup is None else float(tup[i]))]
-            # per-query filter exchange is modeled-only here: the measured
-            # counterpart is cumulative and amortized across the word cache
-            # (see the server-level pair in reconciliation_report)
-            pairs.append(recon_pair("filter_exchange_bytes", fe_model, None))
-            if not meshless:
-                # static collective-buffer model vs live tuple bytes: the
-                # gap is the dense dataflow's buffer slack
-                pairs.append(recon_pair("dist_wire_bytes_model", wire,
-                                        float(tup[i])))
-            if req._bytes_model is not None:
-                # compile-time plan-node model vs this execution's serve-
-                # time restatement of the same §3.1 cost
-                pairs.append(recon_pair(
-                    "node_bytes_model",
-                    float(req._bytes_model["bytes_pushdown"]),
-                    live_model + fe_model))
-            rec = {"query_id": req.query_id, "path": path,
-                   "stream": req.stream, "window_id": req.window_id,
-                   "plan": req.plan, "plan_node": req.plan_node,
-                   "pairs": pairs}
-            if dev is not None:
-                rec["per_device"] = {"modeled": [wire / k] * k,
-                                     "measured": [float(x) for x in dev[i]]}
-            out[id(req)] = rec
-        return out
+            out[id(req)] = {
+                "query_id": req.query_id, "path": path,
+                "stream": req.stream, "window_id": req.window_id,
+                "plan": req.plan, "plan_node": req.plan_node,
+                "meter": partial(_metered_pairs, fetch, i, fe_model, wire, k,
+                                 req._bytes_model)}
+        return fe_model, out
 
     def reconciliation_report(self) -> dict:
         """Modeled-vs-metered byte report: per-query records (traced

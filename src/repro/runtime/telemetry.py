@@ -5,7 +5,8 @@ Three cooperating pieces, shared by every serving layer (`JoinServer`,
 
 * `Tracer` — per-query / per-window / per-plan-node spans (ingest,
   admission/shed, batch-formation, compile, prepare / filter-exchange /
-  shuffle / sample, complete) recorded into a bounded ring.  Disabled
+  shuffle / sample, complete), the engine's own host phases and the
+  process's garbage collections (`GcSpans`) recorded into a bounded ring.  Disabled
   tracers cost one attribute read per call site (`span()` hands back a
   shared no-op span; `instant()`/`event()` return immediately), so the
   hot path is unchanged with tracing off.  Rings export as Chrome
@@ -22,6 +23,9 @@ Three cooperating pieces, shared by every serving layer (`JoinServer`,
   its metered counterpart (`per_device_shuffled_bytes`,
   `dist_shuffled_tuple_bytes`, `kernel_gather_bytes`) and the relative
   model error, aggregated per serving path by `reconciliation_report`.
+  A record may defer its metered numbers (`resolve_recon`): they stay on
+  the device until the report reads them, so a traced step never waits
+  on the tracer's own device reads.
 
 Crash safety: the only tracer state that must survive failover is the
 span-id sequence (successor spans must not reuse the dead replica's ids);
@@ -30,6 +34,7 @@ Metrics survive via the diagnostics scalar merge that already existed.
 """
 from __future__ import annotations
 
+import gc
 import json
 import re
 import threading
@@ -266,7 +271,9 @@ class Tracer:
         self.events: "deque[Dict[str, Any]]" = deque(maxlen=self.capacity)
         self.recon: "deque[Dict[str, Any]]" = deque(maxlen=self.capacity)
         self.tags = dict(tags or {})
-        self._lock = threading.Lock()
+        # re-entrant: a garbage collection can start on a thread that holds
+        # it, and its GcSpans callback records an event on that thread
+        self._lock = threading.RLock()
         self._seq = 0
 
     # -- ids / crash-safety ------------------------------------------------
@@ -325,6 +332,45 @@ class Tracer:
 #: never branch on `tracer is None`.  Never enable or `adopt()` onto it.
 NULL_TRACER = Tracer(enabled=False, capacity=1)
 
+#: Span categories that are always leaves: a span of one of them may overlap
+#: the spans of its lane only in part (a collection run by another thread
+#: stops the lane's thread wherever it is), so `span_tree` and
+#: `chrome_trace` never nest anything under it.
+LEAF_CATS = frozenset({"gc"})
+
+
+class GcSpans:
+    """Record every garbage collection of the process as a ``gc`` span.
+
+    A collection holds the interpreter lock, so it stops the engine's thread
+    whichever thread runs it; the span goes on ``lane`` with the
+    collection's ``generation``, ``collected`` count and the ``thread`` that
+    ran it.  Construction appends the hook to ``gc.callbacks`` and
+    :meth:`close` takes it out — callers create one only for an enabled
+    tracer, so an untraced process keeps ``gc.callbacks`` as it was.  Each
+    instance records every collection, so replicas sharing one tracer each
+    see it on their own lane.
+    """
+
+    def __init__(self, tracer: Tracer, lane: str):
+        self.tracer, self.lane = tracer, lane
+        self._t0 = 0.0
+        gc.callbacks.append(self._callback)
+
+    def _callback(self, phase: str, info: Dict[str, Any]) -> None:
+        if phase == "start":
+            self._t0 = perf_counter()
+            return
+        self.tracer.event("gc", self._t0, perf_counter() - self._t0,
+                          cat="gc", tid=self.lane,
+                          generation=info["generation"],
+                          collected=info["collected"],
+                          thread=threading.current_thread().name)
+
+    def close(self) -> None:
+        if self._callback in gc.callbacks:
+            gc.callbacks.remove(self._callback)
+
 
 # --------------------------------------------------------------------------
 # chrome trace export
@@ -339,11 +385,15 @@ def chrome_trace(tracer: Tracer,
     One pid per replica tag, one tid row per lane string; "M" metadata events
     name both so Perfetto shows readable tracks.  Extra top-level keys
     (`otherData`, `reconciliation`) are ignored by viewers but carried for
-    `trace_dump`.
+    `trace_dump`.  A `LEAF_CATS` span is cut at every boundary of the other
+    spans of its row, so each piece nests as a leaf where the whole would
+    overlap a span only in part; the pieces share its ``span_id``.
     """
     pids: Dict[str, int] = {}
     tids: Dict[Tuple[int, str], int] = {}
     evs: List[Dict[str, Any]] = []
+    leaves: List[Tuple[int, int, Dict[str, Any], Dict[str, Any]]] = []
+    bounds: Dict[Tuple[int, int], set] = {}
     for e in tracer.events:
         proc = str(e["args"].get("replica", tracer.tags.get("replica",
                                                             "serve")))
@@ -365,10 +415,22 @@ def chrome_trace(tracer: Tracer,
             evs.append({"name": e["name"], "cat": e["cat"], "ph": "i",
                         "ts": ts_us, "pid": pid, "tid": tid, "s": "t",
                         "args": args})
+        elif e["cat"] in LEAF_CATS:
+            leaves.append((pid, tid, e, args))
         else:
             evs.append({"name": e["name"], "cat": e["cat"], "ph": "X",
                         "ts": ts_us, "dur": e["dur"] * 1e6, "pid": pid,
                         "tid": tid, "args": args})
+            bounds.setdefault((pid, tid), set()).update(
+                (ts_us, ts_us + e["dur"] * 1e6))
+    for pid, tid, e, args in leaves:
+        t0 = e["ts"] * 1e6
+        t1 = t0 + e["dur"] * 1e6
+        edges = [t0, *sorted(b for b in bounds.get((pid, tid), ())
+                             if t0 < b < t1), t1]
+        evs.extend({"name": e["name"], "cat": e["cat"], "ph": "X", "ts": a,
+                    "dur": b - a, "pid": pid, "tid": tid, "args": args}
+                   for a, b in zip(edges, edges[1:]))
     out: Dict[str, Any] = {"traceEvents": evs, "displayTimeUnit": "ms",
                            "otherData": {"tags": dict(tracer.tags)}}
     if reconciliation is not None:
@@ -423,7 +485,8 @@ def span_tree(events: Iterable[Dict[str, Any]]) -> List[Dict[str, Any]]:
 
     Returns a forest of `{"name", "cat", "ts", "dur", "args", "children"}`
     nodes — the per-query span tree when given one query's events (see
-    `JoinServer.query_trace`)."""
+    `JoinServer.query_trace`).  A `LEAF_CATS` span hangs under the innermost
+    span that holds it whole and never takes children."""
     lanes: Dict[str, List[Dict[str, Any]]] = {}
     for e in events:
         if e.get("dur") is None:
@@ -438,6 +501,12 @@ def span_tree(events: Iterable[Dict[str, Any]]) -> List[Dict[str, Any]]:
                     "dur": e["dur"], "args": e["args"], "children": []}
             end = e["ts"] + e["dur"]
             eps = 1e-9
+            if e.get("cat") in LEAF_CATS:
+                parent = next(
+                    (n for n in reversed(stack) if n["ts"] <= e["ts"] + eps
+                     and end <= n["ts"] + n["dur"] + eps), None)
+                (parent["children"] if parent else forest).append(node)
+                continue
             while stack and end > stack[-1]["ts"] + stack[-1]["dur"] + eps:
                 stack.pop()
             (stack[-1]["children"] if stack else forest).append(node)
@@ -464,6 +533,20 @@ def recon_pair(name: str, modeled: float,
             "rel_error": rel}
 
 
+def resolve_recon(record: Dict[str, Any]) -> Dict[str, Any]:
+    """Fill in a reconciliation record's deferred numbers, once.
+
+    A record may carry ``"meter"``: a callable returning the fields that
+    need the device's meters (``pairs`` and, on a mesh, ``per_device``).
+    The serving engine writes records that way so a traced step never
+    waits on the device for them; the read happens here, when a report
+    is built."""
+    meter = record.pop("meter", None)
+    if meter is not None:
+        record.update(meter())
+    return record
+
+
 def reconciliation_report(records: Iterable[Dict[str, Any]],
                           server_pairs: Optional[List[Dict[str, Any]]] = None
                           ) -> Dict[str, Any]:
@@ -473,7 +556,7 @@ def reconciliation_report(records: Iterable[Dict[str, Any]],
     `path` tag and a `pairs` list); `server_pairs` are cumulative
     server-level pairs (amortized costs that have no per-query meter, e.g.
     the filter exchange, which is cached across queries)."""
-    records = list(records)
+    records = [resolve_recon(r) for r in list(records)]
     paths: Dict[str, Dict[str, Dict[str, float]]] = {}
     for r in records:
         agg = paths.setdefault(r["path"], {})

@@ -8,7 +8,13 @@ excluded everywhere else — keep it runnable on 1 device: multi-device
 cases must skip, not fail.
 """
 
+import gc
 import json
+import os
+import subprocess
+import sys
+import threading
+from collections import Counter
 
 import jax
 import numpy as np
@@ -19,7 +25,7 @@ from repro.core.plan import Plan, PlanNode
 from repro.core.relation import relation
 from repro.core.window import WindowSpec
 from repro.launch.trace_dump import summarize
-from repro.runtime.async_serve import AsyncJoinFrontDoor
+from repro.runtime.async_serve import AsyncJoinFrontDoor, AsyncJoinServer
 from repro.runtime.fault import InjectedFault
 from repro.runtime.join_serve import (JoinRequest, JoinServer,
                                       ServerDiagnostics)
@@ -295,6 +301,7 @@ def test_single_device_span_tree_and_recon():
 
 
 def test_tracing_off_serves_bit_identical_and_silent():
+    hooks = list(gc.callbacks)
     on = JoinServer(batch_slots=2, tracer=Tracer(enabled=True))
     off = JoinServer(batch_slots=2)
     a = on.submit(_req(5, qid="t/q"))
@@ -305,6 +312,201 @@ def test_tracing_off_serves_bit_identical_and_silent():
     assert not off.tracer.events and not off.tracer.recon
     assert off.query_trace("t/q") == []
     assert off.reconciliation_report()["paths"] == {}
+    # the async tier untraced: no collection hook, no loop span, same answer
+    with AsyncJoinServer(JoinServer(batch_slots=2), name="r0") as srv:
+        assert gc.callbacks == hooks
+        c = srv.submit(_req(5, qid="t/q")).result(timeout=120)
+    assert _identical(c.result, b.result)
+    assert gc.callbacks == hooks
+    assert not srv.tracer.events
+
+
+def test_traced_step_reads_the_device_no_more_than_untraced(monkeypatch):
+    """The tracer adds no device read to a step: its reconciliation meters
+    stay on the device until a report is built.  Untraced, the step waits
+    only on prepare (``d_filter`` needs it); traced, also on each stage it
+    times."""
+    counts = Counter()
+    get, block = jax.device_get, jax.block_until_ready
+
+    def counted_get(x):
+        counts["get"] += 1
+        return get(x)
+
+    def counted_block(x):
+        counts["block"] += 1
+        return block(x)
+    monkeypatch.setattr(jax, "device_get", counted_get)
+    monkeypatch.setattr(jax, "block_until_ready", counted_block)
+
+    def one_step(srv, seed):
+        # a dataset handle: its filter words are cached after the first step
+        srv.submit(_req(seed, qid="t0/q", rels=None, dataset="ds"))
+        srv.submit(_req(seed, qid="t1/q", rels=None, dataset="ds",
+                        budget=QueryBudget()))
+        counts.clear()
+        assert srv.step() == 2
+        return dict(counts)
+
+    got = {}
+    for tracer in (None, Tracer(enabled=True)):
+        srv = JoinServer(batch_slots=2, tracer=tracer)
+        srv.register_dataset("ds", _mb(3))
+        one_step(srv, 2)                                         # warm-up
+        got[tracer is not None] = one_step(srv, 2)
+    off, on = got[False], got[True]
+    assert off["block"] == 1
+    assert on["block"] == off["block"] + 2                       # sample, exact
+    assert on["get"] == off["get"]
+    assert srv.reconciliation_report()["paths"]["single"]        # read later
+
+
+# a traced step is tiled by its host phases and stages, in this order
+_PHASES = ("inputs", "prepare", "decide", "sample|exact", "finish")
+
+
+def _check_phases(events, lane):
+    """The last traced step on ``lane``: ``inputs``, ``prepare``,
+    ``decide``, ``sample``/``exact`` and ``finish`` follow one another
+    without overlap inside the ``step`` span and cover >= 90% of it; the
+    ``complete`` span follows the step."""
+    mine = [e for e in events if e["tid"] == lane and e["dur"] is not None]
+    step = max((e for e in mine if e["name"] == "step"),
+               key=lambda e: e["ts"])
+    lo, hi, eps = step["ts"], step["ts"] + step["dur"], 1e-6
+    names = {n for p in _PHASES for n in p.split("|")}
+    inner = sorted((e for e in mine if e["name"] in names
+                    and lo - eps <= e["ts"] <= hi + eps),
+                   key=lambda e: e["ts"])
+    order = [e["name"] for e in inner]
+    assert order[:3] == ["inputs", "prepare", "decide"], order
+    assert order[-1] == "finish", order
+    assert set(order[3:-1]) and set(order[3:-1]) <= {"sample", "exact"}, \
+        order
+    for a, b in zip(inner, inner[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"] + eps, (a["name"], b["name"])
+    assert inner[-1]["ts"] + inner[-1]["dur"] <= hi + eps
+    assert sum(e["dur"] for e in inner) >= 0.9 * step["dur"], \
+        [(e["name"], e["dur"]) for e in inner] + [("step", step["dur"])]
+    complete = [e for e in mine if e["name"] == "complete"
+                and e["ts"] >= hi - eps]
+    assert complete and min(e["ts"] for e in complete) <= hi + 1e-3
+
+
+_MESH_PHASES = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import json
+import numpy as np, jax
+from jax.sharding import Mesh
+from repro.core.budget import QueryBudget
+from repro.core.relation import relation
+from repro.runtime.join_serve import JoinRequest, JoinServer
+from repro.runtime.telemetry import Tracer
+
+r = np.random.default_rng(7)
+rels = [relation(r.integers(0, 200, 256).astype(np.uint32),
+                 r.normal(10, 2, 256).astype(np.float32)),
+        relation(r.integers(150, 350, 256).astype(np.uint32),
+                 r.normal(5, 1, 256).astype(np.float32))]
+tr = Tracer(enabled=True)
+srv = JoinServer(batch_slots=2, mesh=Mesh(np.array(jax.devices()), ("data",)),
+                 tracer=tr)
+srv.register_dataset("ds", rels)
+for step in range(2):
+    for i, budget in enumerate((QueryBudget(error=0.5), QueryBudget())):
+        srv.submit(JoinRequest(dataset="ds", budget=budget, seed=step,
+                               query_id=f"t{i}/q", max_strata=512,
+                               b_max=256))
+    assert srv.step() == 2
+print("EVENTS " + json.dumps([e for e in tr.events if e["tid"] == "engine"]))
+"""
+
+
+@pytest.mark.parametrize("path", ["single", "kernel", "mesh4"])
+def test_step_phases_tile_the_step(path):
+    """On every serving path a traced step is tiled by its spans (the
+    4-device mesh runs in a subprocess so the suite keeps one device)."""
+    if path == "mesh4":
+        env = dict(os.environ, PYTHONPATH="src")
+        out = subprocess.run([sys.executable, "-c", _MESH_PHASES], env=env,
+                             capture_output=True, text=True, timeout=600,
+                             cwd=os.path.dirname(os.path.dirname(
+                                 os.path.abspath(__file__))))
+        assert out.returncode == 0, out.stderr[-3000:]
+        line = next(ln for ln in out.stdout.splitlines()
+                    if ln.startswith("EVENTS "))
+        events = json.loads(line[len("EVENTS "):])
+        assert {e["args"]["path"] for e in events
+                if e["name"] == "step"} == {"mesh4/exact-parity"}
+    else:
+        tr = Tracer(enabled=True)
+        srv = JoinServer(batch_slots=2, tracer=tr)
+        for seed in (0, 2):        # the first step compiles; check the next
+            srv.submit(_req(seed, qid="t0/q", use_kernels=path == "kernel"))
+            srv.submit(_req(seed + 1, qid="t1/q", budget=QueryBudget(),
+                            use_kernels=path == "kernel"))
+            assert srv.step() == 2
+        events = list(tr.events)
+    _check_phases(events, "engine")
+
+
+def test_gc_span_on_the_engine_lane_and_hook_removed_on_close():
+    """A collection forced from another thread while a traced async server
+    is up lands on the replica's lane with the thread that ran it; close()
+    takes the hook out again."""
+    hooks = list(gc.callbacks)
+    tr = Tracer(enabled=True)
+    srv = AsyncJoinServer(JoinServer(batch_slots=2, tracer=tr), name="r0")
+    try:
+        assert len(gc.callbacks) == len(hooks) + 1
+        srv.submit(_req(0, qid="t/q")).result(timeout=120)
+        t = threading.Thread(target=gc.collect, name="collector")
+        t.start()
+        t.join(60)
+        assert not t.is_alive()
+    finally:
+        srv.close()
+    assert gc.callbacks == hooks
+    spans = [e for e in tr.events if e["name"] == "gc"]
+    assert any(e["tid"] == "r0" and e["cat"] == "gc"
+               and e["args"]["thread"] == "collector"
+               and e["args"]["generation"] == 2 for e in spans), spans
+    n = len(tr.events)
+    gc.collect()
+    assert len(tr.events) == n                # nothing after close()
+
+
+def test_gc_span_is_a_leaf_in_tree_and_export():
+    """A collection that overlaps a stage span only in part (run by
+    another thread) stays a leaf: span_tree hangs it under the span that
+    holds it whole, and the Chrome export cuts it where its row's spans
+    start or end, so every piece nests."""
+    tr = Tracer(enabled=True)
+    tr.event("step", 0.0, 10.0, tid="L")
+    tr.event("prepare", 1.0, 4.0, cat="stage", tid="L")
+    tr.event("decide", 5.0, 1.0, cat="engine", tid="L")
+    tr.event("exact", 6.0, 3.0, cat="stage", tid="L")
+    tr.event("gc", 4.0, 1.5, cat="gc", tid="L", thread="client")
+    (step,) = span_tree(tr.events)
+    assert [c["name"] for c in step["children"]] == [
+        "prepare", "gc", "decide", "exact"]
+    assert all(not c["children"] for c in step["children"])
+
+    obj = chrome_trace(tr)
+    validate_chrome_trace(obj)
+    xs = [e for e in obj["traceEvents"] if e["ph"] == "X"]
+    pieces = [e for e in xs if e["name"] == "gc"]
+    assert [(p["ts"], p["dur"]) for p in pieces] == [(4e6, 1e6), (5e6, 5e5)]
+    assert len({p["args"]["span_id"] for p in pieces}) == 1
+    for a in xs:
+        for b in xs:
+            a0, a1 = a["ts"], a["ts"] + a["dur"]
+            b0, b1 = b["ts"], b["ts"] + b["dur"]
+            assert (a1 <= b0 or b1 <= a0 or a0 <= b0 <= b1 <= a1
+                    or b0 <= a0 <= a1 <= b1), (a["name"], b["name"])
+            if a["name"] == "gc" and b["name"] != "gc":
+                assert not (a0 <= b0 and b1 <= a1), b["name"]  # a leaf
 
 
 def test_kernel_path_span_tree():
