@@ -368,10 +368,13 @@ def dist_exact_stage(sorted_rels: Sequence[Relation], local_strata: Strata,
                      agg: str = "sum", expr: str = "sum"):
     """§3.1.1 exact path: per-device per-stratum sums, merged, finished.
 
-    ``per_stratum_value_sums`` is offset-independent (scatter-add), so each
-    device reproduces the single-device per-stratum sums bit-for-bit; the
-    merge re-slots them and ``exact_stage_from_sums`` is the same finishing
-    arithmetic the single-device stage runs.
+    ``per_stratum_value_sums`` adds each stratum's own rows only, in their
+    sorted order, so each device reproduces the single-device per-stratum
+    sums bit-for-bit; it reads row slots off ``local_strata``'s segments,
+    which ``build_strata`` located in these same ``sorted_rels`` (the merged
+    strata carry none).  The merge re-slots the sums and
+    ``exact_stage_from_sums`` is the same finishing arithmetic the
+    single-device stage runs.
     """
     S = merged_strata.keys.shape[0]
     S_k_local = per_stratum_value_sums(sorted_rels, local_strata)

@@ -203,19 +203,37 @@ def sample_edges(sorted_rels: Sequence[Relation], strata: Strata,
 def per_stratum_value_sums(sorted_rels, strata) -> jnp.ndarray:
     """[n_sides, S] sum of values per stratum per side.
 
-    Scatter-add keyed by stratum slot rather than a cumsum-difference: each
-    stratum's sum then depends only on its OWN rows (same relative order),
-    never on what happens to sort before them — which is what lets a device
-    holding a shuffled subset of the strata reproduce the single-device
-    per-stratum sums bit-for-bit (core/distributed.py relies on this).
+    Requires ``strata`` to have been built by ``build_strata`` from these
+    same ``sorted_rels``: each row's stratum slot is read off the segments
+    ``strata.starts``/``strata.counts`` (disjoint, in key order), with no
+    per-row search.  Merged strata (``merge_strata``) carry no segments and
+    must never reach this function.
+
+    Scatter-add keyed by stratum slot rather than a cumsum-difference of the
+    values: each stratum's sum then depends only on its OWN rows (same
+    relative order), never on the rows sorted before it — which is what
+    lets a device holding a shuffled subset of the strata reproduce the
+    single-device per-stratum sums bit-for-bit (core/distributed.py relies
+    on this).
     """
     S = strata.keys.shape[0]
+    label = jnp.arange(1, S + 1, dtype=jnp.int32)
     sums = []
     for side, r in enumerate(sorted_rels):
-        mk = r.masked_keys(SENTINEL)
-        slot = jnp.clip(jnp.searchsorted(strata.keys, mk), 0, S - 1)
-        ok = r.valid & (strata.keys[slot] == mk) & strata.valid[slot]
-        tgt = jnp.where(ok, slot, S)  # overflow row, dropped
+        n = r.keys.shape[0]
+        start, count = strata.starts[side], strata.counts[side]
+        # +(i+1) where stratum i's segment opens, -(i+1) where it closes:
+        # the prefix sum is i+1 inside it and 0 between segments.  Empty
+        # and unused slots mark past the end (dropped): their cancelling
+        # marks would otherwise pile onto one row and slow the scatter.
+        opens = jnp.where(count > 0, start, n)
+        closes = jnp.where(count > 0, start + count, n)
+        edges = jnp.zeros((n,), jnp.int32).at[
+            jnp.concatenate([opens, closes])].add(
+            jnp.concatenate([label, -label]), mode="drop")
+        inside = jnp.cumsum(edges)
+        ok = r.valid & (inside > 0)
+        tgt = jnp.where(ok, inside - 1, S)  # overflow row, dropped
         sums.append(jnp.zeros((S + 1,), jnp.float32).at[tgt].add(
             jnp.where(ok, r.values, 0.0))[:S])
     return jnp.stack(sums)

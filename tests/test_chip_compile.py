@@ -15,6 +15,7 @@ cannot be read back without one.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +28,7 @@ from repro.core.distributed import make_serve_prepare, planned_bucket_cap
 from repro.core.relation import Relation
 from repro.core.sampling import Strata
 from repro.kernels import ops
-from repro.runtime.join_serve import _make_prepare, _make_sample
+from repro.runtime.join_serve import _make_exact, _make_prepare, _make_sample
 
 ORDERS, CUSTOMERS = 1 << 21, 1 << 18
 MAX_STRATA, B_MAX, SLOTS = 1 << 18, 512, 4
@@ -107,6 +108,13 @@ def _slot_rels(sh):
             for n in (ORDERS, CUSTOMERS)]
 
 
+def _slot_strata(sh):
+    S = MAX_STRATA
+    return Strata(*_shapes(sh, ((SLOTS, S), jnp.uint32), ((SLOTS, S), bool),
+                           ((SLOTS, 2, S), jnp.int32),
+                           ((SLOTS, 2, S), jnp.int32), ((SLOTS,), jnp.int32)))
+
+
 @pytest.mark.parametrize("stage", ["prepare", "sample"])
 def test_jnp_stage_fits_one_chip(one_chip, stage):
     """The server's jnp stage executables at the smoke's SF1 class and
@@ -118,15 +126,20 @@ def test_jnp_stage_fits_one_chip(one_chip, stage):
         words = _shapes(sh, ((SLOTS, 2, NUM_BLOCKS, 8), jnp.uint32))[0]
         _, temp = _compile(_make_prepare(MAX_STRATA), rels, words, seeds)
     else:
-        S = MAX_STRATA
-        strata = Strata(*_shapes(sh, ((SLOTS, S), jnp.uint32),
-                                 ((SLOTS, S), bool),
-                                 ((SLOTS, 2, S), jnp.int32),
-                                 ((SLOTS, 2, S), jnp.int32),
-                                 ((SLOTS,), jnp.int32)))
-        b_i = _shapes(sh, ((SLOTS, S), jnp.float32))[0]
+        b_i = _shapes(sh, ((SLOTS, MAX_STRATA), jnp.float32))[0]
         _, temp = _compile(_make_sample(B_MAX, "sum", False, 0.95, "sum"),
-                           rels, strata, b_i, seeds)
+                           rels, _slot_strata(sh), b_i, seeds)
+    assert temp < HBM_BYTES, temp
+
+
+def test_exact_stage_has_no_search_loop(one_chip):
+    """The served exact stage at the SF1 class and 4-slot batch fits one
+    chip and has no ``while`` op: each row's stratum slot is read off the
+    strata's segments, not found by a binary search (which the TPU compiler
+    emits as a loop)."""
+    compiled, temp = _compile(_make_exact("sum", "sum"),
+                              _slot_rels(one_chip), _slot_strata(one_chip))
+    assert not re.search(r"\)\s+while\(", compiled.as_text())
     assert temp < HBM_BYTES, temp
 
 

@@ -3,15 +3,17 @@ draws, exact sufficient-statistics oracles (hypothesis property tests)."""
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from conftest import hypothesis_or_stubs
 from repro.core.hashing import hash2
 from repro.core.relation import relation, sort_by_key
-from repro.core.sampling import (build_strata, exact_count,
+from repro.core.sampling import (SENTINEL, build_strata, exact_count,
                                  exact_sum_of_products, exact_sum_of_sums,
-                                 reservoir_empty, reservoir_extend,
-                                 reservoir_fill, reservoir_merge,
-                                 reservoir_moments, sample_edges)
+                                 per_stratum_value_sums, reservoir_empty,
+                                 reservoir_extend, reservoir_fill,
+                                 reservoir_merge, reservoir_moments,
+                                 sample_edges)
 
 given, settings, st = hypothesis_or_stubs()
 
@@ -127,6 +129,58 @@ def test_strata_overflow_counted():
     strata = build_strata([r1, r2], max_strata=32)
     assert int(strata.overflow) == 100 - 32
     assert int(strata.num_strata) == 32
+
+
+def _searchsorted_value_sums(sorted_rels, strata):
+    """Reference: each row's stratum slot by a binary search of the strata
+    keys, checked against the key and the slot's validity, then the same
+    scatter-add ``per_stratum_value_sums`` does."""
+    S = strata.keys.shape[0]
+    sums = []
+    for r in sorted_rels:
+        mk = r.masked_keys(SENTINEL)
+        slot = jnp.clip(jnp.searchsorted(strata.keys, mk), 0, S - 1)
+        ok = r.valid & (strata.keys[slot] == mk) & strata.valid[slot]
+        tgt = jnp.where(ok, slot, S)
+        sums.append(jnp.zeros((S + 1,), jnp.float32).at[tgt].add(
+            jnp.where(ok, r.values, 0.0))[:S])
+    return jnp.stack(sums)
+
+
+# per side: (lowest key, highest key + 1, rows, share of rows valid), each
+# side padded with invalid rows to 256; then max_strata
+SEGMENT_CASES = {
+    "two_way": ([(0, 40, 200, 0.8), (10, 50, 120, 0.8)], 64),
+    "three_way": ([(0, 30, 200, 0.9), (5, 35, 150, 0.7),
+                   (0, 20, 100, 1.0)], 64),
+    "keys_in_one_side_only": ([(0, 20, 100, 1.0), (100, 140, 100, 1.0)],
+                              64),
+    "side_missing_strata": ([(0, 60, 200, 1.0), (0, 10, 50, 1.0)], 64),
+    "strata_overflow": ([(0, 200, 250, 1.0), (0, 200, 250, 0.9)], 16),
+    "all_invalid_side": ([(0, 40, 200, 1.0), (0, 40, 100, 0.0)], 64),
+    "all_invalid_lead": ([(0, 40, 200, 0.0), (0, 40, 100, 1.0)], 64),
+    # valid rows keyed SENTINEL share their segment with the invalid rows
+    "sentinel_keys": ([(SENTINEL - 2, SENTINEL + 1, 200, 0.7),
+                       (SENTINEL - 2, SENTINEL + 1, 100, 0.7)], 64),
+}
+
+
+@pytest.mark.parametrize("case", list(SEGMENT_CASES))
+def test_value_sums_match_searchsorted_slots(case):
+    """Stratum slots read off the strata's segments give bit-identical
+    per-stratum sums to a per-row search of the strata keys."""
+    sides, max_strata = SEGMENT_CASES[case]
+    rng = np.random.default_rng(sorted(SEGMENT_CASES).index(case))
+    rels = []
+    for lo, hi, n, share in sides:
+        keys = rng.integers(lo, hi, 256).astype(np.uint32)
+        vals = rng.normal(2.0, 1.0, 256).astype(np.float32)
+        valid = (np.arange(256) < n) & (rng.random(256) < share)
+        rels.append(sort_by_key(relation(keys, vals, valid)))
+    strata = build_strata(rels, max_strata)
+    got = per_stratum_value_sums(rels, strata)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(
+        _searchsorted_value_sums(rels, strata)))
 
 
 # ---------------------------------------------------------------------------
