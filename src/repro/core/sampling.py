@@ -15,8 +15,10 @@ partitioned across devices — the coordination-free property the paper needs
 for distributed sampling, made exact here.
 
 The group-by machinery (``build_strata``) identifies strata from the sorted
-lead relation and locates each stratum's segment in every side with
-``searchsorted`` — O(N log N), no hash tables, no dynamic shapes.
+lead relation, reads their segments in the lead off its own key runs, and
+locates them in every other side by one merge of the sorted stratum keys
+with the side's sorted keys (one sort, one prefix sum, one scatter) — no
+binary search, no hash tables, no dynamic shapes.
 """
 
 from __future__ import annotations
@@ -63,39 +65,79 @@ class Strata(NamedTuple):
         return jnp.sum(self.joinable.astype(jnp.int32))
 
 
-def _segment(sorted_keys: jnp.ndarray, stratum_keys: jnp.ndarray):
-    start = jnp.searchsorted(sorted_keys, stratum_keys, side="left")
-    end = jnp.searchsorted(sorted_keys, stratum_keys, side="right")
-    return start.astype(jnp.int32), (end - start).astype(jnp.int32)
+def _merge_segments(side_keys: jnp.ndarray, stratum_keys: jnp.ndarray):
+    """(start, count) of each stratum key's run in the ascending
+    ``side_keys``: what ``searchsorted`` left and right give, from one merge.
+
+    One stable sort of [stratum keys, their successors key + 1, side keys]
+    puts each of the first two before the side keys equal to it, so the
+    side keys counted up to it are the side keys below it: for a stratum
+    key its start, for its successor its end (the side keys at or below
+    the stratum key).  Both are scattered back to the strata's slots and
+    the side's elements are dropped.  A stratum keyed SENTINEL, whose
+    successor wraps to 0, ends at the end of the side.
+    """
+    S, n = stratum_keys.shape[0], side_keys.shape[0]
+    _, at = jax.lax.sort(
+        (jnp.concatenate([stratum_keys, stratum_keys + u32(1), side_keys]),
+         jnp.arange(2 * S + n, dtype=jnp.int32)), num_keys=1, is_stable=True)
+    below = jnp.cumsum((at >= 2 * S).astype(jnp.int32))
+    bounds = jnp.zeros((2 * S,), jnp.int32).at[jnp.where(
+        at < 2 * S, at, 2 * S)].set(below, mode="drop")
+    ends = jnp.where(stratum_keys == u32(SENTINEL), n, bounds[S:])
+    return bounds[:S], ends - bounds[:S]
 
 
 @jax.named_scope("strata")
 def build_strata(sorted_rels: Sequence[Relation], max_strata: int) -> Strata:
     """Identify strata from sorted_rels[0]; locate segments in every side.
 
-    All relations must already be sorted by ``masked_keys()`` (invalid rows
-    filled with SENTINEL sort last).  Strata beyond ``max_strata`` are counted
-    in ``overflow`` (they are dropped; callers size S = key capacity to make
-    this impossible in exact mode).
+    Preconditions: every relation is sorted by ``masked_keys(SENTINEL)``
+    (invalid rows filled with SENTINEL sort last, as ``sort_by_key`` does);
+    the stratum keys built here are then ascending and distinct, but for
+    the SENTINEL pads of unused slots (a valid lead row keyed SENTINEL makes
+    one valid stratum keyed SENTINEL, last).
+
+    Each stratum is a run of the lead's masked keys that starts at a valid
+    row, and that run is its segment in the lead: from its first row to the
+    next stratum's first row, read off the same scatter as its key.  In
+    every other side its segment is found by one merge of the stratum keys
+    with the side's masked keys (:func:`_merge_segments`).  Either way
+    ``starts``/``counts`` are what ``searchsorted(side, key, "left")`` and
+    the right search's difference give, also in unused slots (start: the
+    side's rows below SENTINEL; count 0).  Strata beyond ``max_strata`` are
+    counted in ``overflow`` (they are dropped; callers size S = key capacity
+    to make this impossible in exact mode).
     """
     lead = sorted_rels[0]
     mk = lead.masked_keys(SENTINEL)
-    first = jnp.ones((1,), bool) if mk.shape[0] else jnp.zeros((0,), bool)
+    n = mk.shape[0]
+    first = jnp.ones((min(n, 1),), bool)
     is_start = lead.valid & jnp.concatenate([first, mk[1:] != mk[:-1]])
     sid = jnp.cumsum(is_start.astype(jnp.int32)) - 1  # stratum index per row
     total = jnp.sum(is_start.astype(jnp.int32))
     S = max_strata
-    slot = jnp.where(is_start & (sid < S), sid, S)  # overflow -> row S
+    # row S takes stratum S (where the lead's slot S - 1 ends); later drop
+    slot = jnp.where(is_start, sid, S + 1)
     keys = jnp.full((S + 1,), SENTINEL, jnp.uint32).at[slot].set(mk,
                                                                  mode="drop")
     keys = keys[:S]
     valid = jnp.arange(S) < jnp.minimum(total, S)
     keys = jnp.where(valid, keys, u32(SENTINEL))
-    starts, counts = [], []
-    for r in sorted_rels:
-        s, c = _segment(r.masked_keys(SENTINEL), keys)
+    # the lead: stratum i's run starts at its is_start row and ends where
+    # stratum i + 1's starts, or else where the masked keys reach SENTINEL
+    # (which is also where the left search of SENTINEL lands); a stratum
+    # keyed SENTINEL runs to the end
+    below = jnp.sum((mk != u32(SENTINEL)).astype(jnp.int32))
+    bounds = jnp.full((S + 1,), below, jnp.int32).at[slot].set(
+        jnp.arange(n, dtype=jnp.int32), mode="drop")
+    ends = jnp.where(keys == u32(SENTINEL), n, bounds[1:])
+    starts, counts = [bounds[:S]], [ends - bounds[:S]]
+    for r in sorted_rels[1:]:
+        s, c = _merge_segments(r.masked_keys(SENTINEL), keys)
         starts.append(s)
-        counts.append(jnp.where(valid, c, 0))
+        counts.append(c)
+    counts = [jnp.where(valid, c, 0) for c in counts]
     return Strata(keys, valid,
                   jnp.stack(starts), jnp.stack(counts),
                   jnp.maximum(total - S, 0))

@@ -28,7 +28,8 @@ from repro.core.distributed import make_serve_prepare, planned_bucket_cap
 from repro.core.relation import Relation
 from repro.core.sampling import Strata
 from repro.kernels import ops
-from repro.runtime.join_serve import _make_exact, _make_prepare, _make_sample
+from repro.runtime.join_serve import (_make_exact, _make_prepare,
+                                      _make_prepare_kernels, _make_sample)
 
 ORDERS, CUSTOMERS = 1 << 21, 1 << 18
 MAX_STRATA, B_MAX, SLOTS = 1 << 18, 512, 4
@@ -140,6 +141,22 @@ def test_exact_stage_has_no_search_loop(one_chip):
     compiled, temp = _compile(_make_exact("sum", "sum"),
                               _slot_rels(one_chip), _slot_strata(one_chip))
     assert not re.search(r"\)\s+while\(", compiled.as_text())
+    assert temp < HBM_BYTES, temp
+
+
+def test_prepare_stage_has_no_search_loop(one_chip):
+    """The served kernel prepare at the SF1 class and 4-slot batch fits one
+    chip and its strata build has no ``while`` op: each stratum's segment
+    is read off the lead's key runs and found in the other side by one
+    merge, not by binary searches (which the TPU compiler emits as
+    loops)."""
+    sh = one_chip
+    compiled, temp = _compile(
+        _make_prepare_kernels(MAX_STRATA), _slot_rels(sh),
+        *_shapes(sh, ((SLOTS, 2, NUM_BLOCKS, 8), jnp.uint32),
+                 ((SLOTS,), jnp.uint32)))
+    assert not re.search(r"\)\s+while\(.*op_name=\"[^\"]*strata",
+                         compiled.as_text())
     assert temp < HBM_BYTES, temp
 
 

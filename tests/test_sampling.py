@@ -165,22 +165,71 @@ SEGMENT_CASES = {
 }
 
 
-@pytest.mark.parametrize("case", list(SEGMENT_CASES))
-def test_value_sums_match_searchsorted_slots(case):
-    """Stratum slots read off the strata's segments give bit-identical
-    per-stratum sums to a per-row search of the strata keys."""
-    sides, max_strata = SEGMENT_CASES[case]
-    rng = np.random.default_rng(sorted(SEGMENT_CASES).index(case))
+def _segment_rels(cases, case):
+    sides, max_strata = cases[case]
+    rng = np.random.default_rng(sorted(cases).index(case))
     rels = []
     for lo, hi, n, share in sides:
         keys = rng.integers(lo, hi, 256).astype(np.uint32)
         vals = rng.normal(2.0, 1.0, 256).astype(np.float32)
         valid = (np.arange(256) < n) & (rng.random(256) < share)
         rels.append(sort_by_key(relation(keys, vals, valid)))
+    return rels, max_strata
+
+
+@pytest.mark.parametrize("case", list(SEGMENT_CASES))
+def test_value_sums_match_searchsorted_slots(case):
+    """Stratum slots read off the strata's segments give bit-identical
+    per-stratum sums to a per-row search of the strata keys."""
+    rels, max_strata = _segment_rels(SEGMENT_CASES, case)
     strata = build_strata(rels, max_strata)
     got = per_stratum_value_sums(rels, strata)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(
         _searchsorted_value_sums(rels, strata)))
+
+
+def _searchsorted_strata(sorted_rels, max_strata):
+    """Reference: strata keys from the lead's run starts, then each
+    stratum's segment in every side by a left and a right binary search."""
+    lead = sorted_rels[0]
+    mk = lead.masked_keys(SENTINEL)
+    is_start = lead.valid & jnp.concatenate(
+        [jnp.ones((1,), bool), mk[1:] != mk[:-1]])
+    sid = jnp.cumsum(is_start.astype(jnp.int32)) - 1
+    total = jnp.sum(is_start.astype(jnp.int32))
+    S = max_strata
+    slot = jnp.where(is_start & (sid < S), sid, S)
+    keys = jnp.full((S + 1,), SENTINEL, jnp.uint32).at[slot].set(
+        mk, mode="drop")[:S]
+    valid = jnp.arange(S) < jnp.minimum(total, S)
+    keys = jnp.where(valid, keys, jnp.uint32(SENTINEL))
+    starts, counts = [], []
+    for r in sorted_rels:
+        side = r.masked_keys(SENTINEL)
+        lo = jnp.searchsorted(side, keys, side="left").astype(jnp.int32)
+        hi = jnp.searchsorted(side, keys, side="right").astype(jnp.int32)
+        starts.append(lo)
+        counts.append(jnp.where(valid, hi - lo, 0))
+    return (keys, valid, jnp.stack(starts), jnp.stack(counts),
+            jnp.maximum(total - S, 0))
+
+
+STRATA_CASES = {**SEGMENT_CASES,
+                "sixteen_strata_long_side": ([(0, 16, 256, 1.0),
+                                              (0, 24, 256, 1.0)], 16)}
+
+
+@pytest.mark.parametrize("case", list(STRATA_CASES))
+def test_strata_match_searchsorted_segments(case):
+    """The lead's run starts and the other sides' merge give bit-identical
+    strata to a binary search of every stratum key in every side."""
+    rels, max_strata = _segment_rels(STRATA_CASES, case)
+    got = build_strata(rels, max_strata)
+    want = _searchsorted_strata(rels, max_strata)
+    for field, w in zip(("keys", "valid", "starts", "counts", "overflow"),
+                        want):
+        np.testing.assert_array_equal(np.asarray(getattr(got, field)),
+                                      np.asarray(w), err_msg=field)
 
 
 # ---------------------------------------------------------------------------
